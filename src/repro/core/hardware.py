@@ -29,6 +29,7 @@ import numpy as np
 __all__ = [
     "HardwareSpec",
     "presets",
+    "TPU_KINDS",
     "query",
     "dtype_bits",
     "sublane_packing",
@@ -112,7 +113,9 @@ class HardwareSpec:
         )
 
 
-# TPU v5e is the primary target (the brief's roofline constants).
+# TPU v5e is the primary target.  Peaks are Google Cloud's published
+# figures for one v5e chip ("TPU v5e" documentation): 197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s of interconnect over four links.
 _TPU_V5E = HardwareSpec(name="tpu_v5e")
 
 presets: dict[str, HardwareSpec] = {
@@ -137,12 +140,22 @@ presets: dict[str, HardwareSpec] = {
 }
 
 
+# ``jax.devices()[0].device_kind`` -> preset, for the chips whose peaks this
+# module holds.  A TPU that is not listed is an error, never a default.
+TPU_KINDS: dict[str, str] = {
+    "TPU v5 lite": "tpu_v5e",
+    "TPU v4": "tpu_v4",
+}
+
+
 def query(name: Optional[str] = None) -> HardwareSpec:
     """Query the hardware descriptor at run time (``svcntw()`` analogue).
 
-    Resolution order: explicit ``name`` → ``$REPRO_HW`` → the actual JAX
-    backend (TPU kind if on TPU) → tpu_v5e default (this container is CPU;
-    v5e is the modelled target).
+    Resolution order: explicit ``name`` → ``$REPRO_HW`` → the attached TPU's
+    ``device_kind`` through :data:`TPU_KINDS` (a kind not in the table
+    raises).  Off TPU the modelled v5e is returned: it sizes the packed
+    layouts and kernel blocks the CPU tests check, and its peaks price no
+    measurement.
     """
     if name is None:
         name = os.environ.get("REPRO_HW")
@@ -151,9 +164,10 @@ def query(name: Optional[str] = None) -> HardwareSpec:
             raise KeyError(f"unknown hardware preset {name!r}; have {sorted(presets)}")
         return presets[name]
     dev = jax.devices()[0]
-    if dev.platform == "tpu":  # pragma: no cover - no TPU in this container
-        kind = getattr(dev, "device_kind", "").lower()
-        if "v4" in kind:
-            return presets["tpu_v4"]
-        return presets["tpu_v5e"]
+    if dev.platform == "tpu":
+        kind = dev.device_kind
+        if kind not in TPU_KINDS:
+            raise KeyError(f"TPU device_kind {kind!r} has no hardware preset; "
+                           f"known kinds: {sorted(TPU_KINDS)}")
+        return presets[TPU_KINDS[kind]]
     return presets["tpu_v5e"]

@@ -1,10 +1,12 @@
 """Dispatch wrapper for segment-masked ragged paged attention.
 
-On TPU the Pallas kernel runs; everywhere else the jnp oracle does.  The
-oracle is not a fallback of convenience: off-TPU the serving engine's
-bitwise flat-vs-dense identity contract is verified against it, so the
-dispatch must happen at trace time (``jax.default_backend()``) — the
-caller (models/attention.py) is already inside the engine's jit.
+On TPU the compiled Pallas kernel always runs: asking for the oracle there
+is an error, so a chip run can never silently measure it.  Off TPU the jnp
+oracle runs by default — the serving engine's bitwise flat-vs-dense
+identity contract is verified against it — and ``use_kernel=True,
+interpret=True`` runs the kernel body in the Pallas interpreter instead.
+The dispatch happens at trace time (``jax.default_backend()``): the caller
+(models/attention.py) is already inside the engine's jit.
 """
 
 from __future__ import annotations
@@ -27,8 +29,12 @@ def ragged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     """q: [W, Hq, dh] flat queries; k_pages/v_pages: [P, T, Hkv, dh] pool;
     block_tables: [B, MP]; row_ids: [W] (-1 = pad); q_pos: [W].
     Returns [W, Hq, dh]."""
+    on_tpu = jax.default_backend() == "tpu"
     if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
+        use_kernel = on_tpu
+    if on_tpu and not use_kernel:
+        raise ValueError("ragged_attention: on TPU the kernel runs; call "
+                         "ragged_attention_reference for the oracle")
     if use_kernel:
         from repro.kernels.ragged_attn.kernel import ragged_attention_kernel_call
         return ragged_attention_kernel_call(

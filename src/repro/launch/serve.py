@@ -7,7 +7,7 @@ comparison).
 
 Usage:
     PYTHONPATH=src python -m repro.launch.serve --arch smollm2-135m \
-        --reduced --requests 8 --slots 4 --new 32
+        --reduced --dtype float32 --requests 8 --slots 4 --new 32
 """
 
 from __future__ import annotations
@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.configs import RunConfig, get_config, reduced_config
 from repro.configs.base import ShapeSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.serving.engine import Engine
 
@@ -38,26 +39,33 @@ def main(argv=None):
     ap.add_argument("--pool-pages", type=int, default=None,
                     help="KV pool size in pages (default: ample); undersized "
                     "pools are served via preemption-by-recomputation")
+    ap.add_argument("--chunk-tokens", type=int, default=None,
+                    help="chunked prefill through the flat token-level step "
+                    "(and the ragged-attention kernel on TPU); default: "
+                    "monolithic prefill")
     ap.add_argument("--eager", action="store_true",
                     help="reserve each request's full KV lifetime at "
                     "admission (the pre-lazy baseline policy)")
     ap.add_argument("--policy", default="scalable")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--dtype", default="bfloat16",
+                    help="param and compute dtype")
     ap.add_argument("--static", action="store_true",
                     help="static-batch baseline (one shared prompt length)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced_config(cfg)
     shape = ShapeSpec("serve", args.max_len, args.slots, "decode")
-    run = RunConfig(layout_policy=args.policy, param_dtype="float32",
-                    compute_dtype="float32", remat=False)
+    run = RunConfig(layout_policy=args.policy, param_dtype=args.dtype,
+                    compute_dtype=args.dtype, remat=False)
     model = build_model(cfg, run, shape)
     params = model.init(jax.random.PRNGKey(args.seed))
     engine = Engine(model, params, max_slots=args.slots,
                     page_tokens=args.page_tokens, num_pages=args.pool_pages,
-                    eager=args.eager)
+                    eager=args.eager, chunk_tokens=args.chunk_tokens)
 
     key = jax.random.PRNGKey(args.seed + 1)
     if args.static or not engine.continuous:

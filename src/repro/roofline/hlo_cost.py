@@ -28,17 +28,9 @@ __all__ = ["parse_hlo", "HloCost", "xla_cost_dict"]
 
 
 def xla_cost_dict(cost) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions.
-
-    Older jaxlibs return a one-element list of per-program dicts, newer ones
-    return the dict directly; either may be ``None`` for backends without a
-    cost model.  Always returns a plain dict.
-    """
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost)
+    """``compiled.cost_analysis()`` as a plain dict (``None`` — a backend
+    without a cost model — gives ``{}``)."""
+    return dict(cost) if cost is not None else {}
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,

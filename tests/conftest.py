@@ -1,28 +1,15 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — tests run on 1 device;
-multi-device dry-run coverage goes through subprocesses (test_dryrun.py).
-
-If ``hypothesis`` is not installed, a seeded-random property-check fallback
-(tests/_propcheck.py) is registered under that name BEFORE test modules
-import — property modules always collect and the properties still run."""
-
-import sys
-
-try:
-    import hypothesis  # noqa: F401  (prefer the real library when present)
-except ImportError:
-    import _propcheck
-    sys.modules["hypothesis"] = _propcheck
+multi-device dry-run coverage goes through subprocesses (test_dryrun.py)."""
 
 import jax
-import jax.numpy as jnp
 import pytest
 
-# NOTE: do NOT enable jax_compilation_cache_dir here — the persistent cache
-# in jaxlib 0.4.37 corrupts the heap on the CPU backend under this suite
-# (reproducible "corrupted double-linked list" abort in the trainer
-# checkpoint-resume test once executables round-trip through the cache).
-
 from repro.configs import RunConfig, ShapeSpec
+
+# The persistent compile cache stays off in tests: the AOT compiles for a
+# described TPU (test_tpu_compile.py) would be written to it but cannot be
+# read back without a chip, so the next run warns and compiles again.
+jax.config.update("jax_enable_compilation_cache", False)
 
 
 @pytest.fixture(scope="session")

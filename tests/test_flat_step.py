@@ -287,17 +287,21 @@ def test_flat_width_ladder(smollm):
 # Pallas kernel vs reference oracle
 # ---------------------------------------------------------------------------
 
-def test_ragged_kernel_matches_reference():
+@pytest.mark.parametrize("hq,hkv,dh,dtype,tol", [
+    (4, 2, 8, jnp.float32, 1e-5),
+    # smollm2-135m's heads in bf16: both sides round once to bf16, so one
+    # ulp (2^-6 at |x| < 4) is the most they may differ by
+    (9, 3, 64, jnp.bfloat16, 2 ** -6),
+], ids=["4-2-8-f32", "9-3-64-bf16"])
+def test_ragged_kernel_matches_reference(hq, hkv, dh, dtype, tol):
     """Interpret-mode Pallas kernel vs the jnp oracle on mixed segments:
     a decode row, a mid-prefill chunk, a fresh prefill and -1 padding."""
     key = jax.random.PRNGKey(0)
-    hq, hkv, dh, t, pages, mp, w = 4, 2, 8, 8, 9, 3, 16
+    t, pages, mp, w = 8, 9, 3, 16
     ks = jax.random.split(key, 3)
-    q = np.asarray(jax.random.normal(ks[0], (w, hq, dh)), np.float32)
-    k_pages = np.asarray(jax.random.normal(ks[1], (pages, t, hkv, dh)),
-                         np.float32)
-    v_pages = np.asarray(jax.random.normal(ks[2], (pages, t, hkv, dh)),
-                         np.float32)
+    q = jax.random.normal(ks[0], (w, hq, dh)).astype(dtype)
+    k_pages = jax.random.normal(ks[1], (pages, t, hkv, dh)).astype(dtype)
+    v_pages = jax.random.normal(ks[2], (pages, t, hkv, dh)).astype(dtype)
     bt = np.asarray(jax.random.permutation(jax.random.PRNGKey(5),
                                            pages)[: 3 * mp],
                     np.int32).reshape(3, mp)
@@ -310,14 +314,13 @@ def test_ragged_kernel_matches_reference():
     row_ids[6:10], q_pos[6:10] = 2, np.arange(4)
     args = dict(block_tables=jnp.asarray(bt), row_ids=jnp.asarray(row_ids),
                 q_pos=jnp.asarray(q_pos))
-    ref = ragged_attention_reference(q, jnp.asarray(k_pages),
-                                     jnp.asarray(v_pages), **args)
-    out = ragged_attention_kernel_call(q, jnp.asarray(k_pages),
-                                       jnp.asarray(v_pages), interpret=True,
+    ref = ragged_attention_reference(q, k_pages, v_pages, **args)
+    out = ragged_attention_kernel_call(q, k_pages, v_pages, interpret=True,
                                        **args)
-    np.testing.assert_allclose(np.asarray(out)[row_ids >= 0],
-                               np.asarray(ref)[row_ids >= 0],
-                               atol=1e-5, rtol=1e-5)
+    assert out.dtype == dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32)[row_ids >= 0],
+                               np.asarray(ref, np.float32)[row_ids >= 0],
+                               atol=tol, rtol=tol)
 
 
 # ---------------------------------------------------------------------------

@@ -61,6 +61,27 @@ def test_hardware_query_env(monkeypatch):
     assert query().name in presets
 
 
+def _fake_tpu(monkeypatch, kind):
+    import jax
+    from types import SimpleNamespace
+    monkeypatch.delenv("REPRO_HW", raising=False)
+    monkeypatch.setattr(jax, "devices", lambda *a: [
+        SimpleNamespace(platform="tpu", device_kind=kind)])
+
+
+@pytest.mark.parametrize("kind,preset", [("TPU v5 lite", "tpu_v5e"),
+                                         ("TPU v4", "tpu_v4")])
+def test_hardware_query_known_tpu_kind(monkeypatch, kind, preset):
+    _fake_tpu(monkeypatch, kind)
+    assert query() is presets[preset]
+
+
+def test_hardware_query_unknown_tpu_kind_raises(monkeypatch):
+    _fake_tpu(monkeypatch, "TPU v99")
+    with pytest.raises(KeyError, match="TPU v99"):
+        query()
+
+
 def test_scaled_spec_controls_only_width():
     """Scaling study premise: compute scales, memory system fixed."""
     hw = presets["tpu_v5e"]
