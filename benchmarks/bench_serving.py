@@ -877,7 +877,7 @@ def bench_attrib(model, params, reqs, slots, chunk_tokens):
             "padding_waste_ratio": at["padding_waste_ratio"],
             "roofline_fraction": at["roofline_fraction"],
             "achieved_tokens_per_s": at["achieved_tokens_per_s"],
-            "device_fraction": tot["device_s"] / max(tot["wall_s"], 1e-12),
+            "device_fraction": tot["call_s"] / max(tot["wall_s"], 1e-12),
             "families": {
                 label: {"steps": f["steps"], "fill": f["fill"],
                         "predicted_vs_measured": f["predicted_vs_measured"]}
@@ -885,7 +885,7 @@ def bench_attrib(model, params, reqs, slots, chunk_tokens):
         }
         print(f"  attribution / {mode:<8} mfu {at['mfu']:.2e}  "
               f"mbu {at['mbu']:.2e}  padding waste "
-              f"{at['padding_waste_ratio']:.3f} of device  "
+              f"{at['padding_waste_ratio']:.3f} of call  "
               f"roofline fraction {at['roofline_fraction']:.3f}  "
               f"({len(at['families'])} families)")
     return out

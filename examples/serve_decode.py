@@ -32,7 +32,7 @@ cancels, quarantines, watchdog trips) are printed at the end.
 With ``--trace-out PATH`` / ``--metrics-json PATH`` the drain runs with
 the :mod:`repro.obs` telemetry subsystem live: the former writes a
 Chrome ``trace_event`` JSON (open in Perfetto or ``chrome://tracing`` —
-one track per slot plus engine/scheduler/pool), the latter dumps the
+one track per slot plus scheduler/pool), the latter dumps the
 full streaming-metrics registry snapshot; either flag also prints a
 compact TTFT/ITL percentile table at the end of the drain.  Telemetry
 is host-side only — the tokens are identical with it on or off.
@@ -97,16 +97,11 @@ def main():
                     help="run with telemetry on and write a Chrome "
                     "trace_event JSON (Perfetto / chrome://tracing) of "
                     "the drain: per-slot prefill/decode spans, queue "
-                    "waits, preempt/pause/shed instants, step phases")
+                    "waits, preempt/pause/shed instants")
     ap.add_argument("--metrics-json", default=None, metavar="PATH",
                     help="run with telemetry on and dump the full "
                     "streaming-metrics registry snapshot (counters, "
                     "gauges, latency histograms) as JSON")
-    ap.add_argument("--report", default=None, metavar="PATH",
-                    help="run with telemetry on and write the single-file "
-                    "HTML attribution report (waterfall, per-family "
-                    "predicted-vs-measured, MFU/MBU, alerts) plus the "
-                    "Prometheus text exposition next to it (.prom)")
     ap.add_argument("--slo-ttft", type=float, default=None,
                     help="TTFT SLO target in seconds: arms the slo-burn "
                     "monitor and defines goodput for first tokens")
@@ -114,8 +109,7 @@ def main():
                     help="ITL SLO target in seconds (slo-burn monitor)")
     args = ap.parse_args()
     want_obs = (args.trace_out is not None or args.metrics_json is not None
-                or args.report is not None or args.slo_ttft is not None
-                or args.slo_itl is not None)
+                or args.slo_ttft is not None or args.slo_itl is not None)
 
     cfg = reduced_config(get_config(args.arch))
     shape = ShapeSpec("serve", args.max_len, args.slots, "decode")
@@ -135,7 +129,7 @@ def main():
         engine.obs.monitors.slo_ttft_s = args.slo_ttft
         engine.obs.monitors.slo_itl_s = args.slo_itl
         # warmup builds the per-family roofline cost model the
-        # attribution report prices padding waste / MFU / MBU against
+        # attribution summary prices padding waste / MFU / MBU against
         engine.warmup()
     rng = np.random.default_rng(1)
     key = jax.random.PRNGKey(1)
@@ -234,7 +228,7 @@ def main():
               f"{sp['rollback_pages']} pages rolled back "
               f"({sp['drafter']})")
     if want_obs:
-        tel = engine.telemetry(report=args.report)
+        tel = engine.telemetry()
         slo = es["slo"]
         print(f"[serve] slo: goodput {slo['goodput_tokens']}/"
               f"{slo['tokens_out']} tokens inside deadline "
@@ -245,19 +239,16 @@ def main():
         if "mfu" in at:
             t = at["totals"]
             print(f"[serve] attribution: wall {t['wall_s']:.3f}s = "
-                  f"sched {t['sched_s']:.3f} + device {t['device_s']:.3f} "
+                  f"sched {t['sched_s']:.3f} + call {t['call_s']:.3f} "
                   f"+ draft {t['draft_s']:.3f} + host {t['host_s']:.3f}; "
                   f"mfu {at['mfu']:.2e}, mbu {at['mbu']:.2e}, "
                   f"padding waste {at['padding_waste_ratio']:.2f} of "
-                  f"device, roofline fraction {at['roofline_fraction']:.3f}")
+                  f"call, roofline fraction {at['roofline_fraction']:.3f}")
         if tel["alerts"]:
             print(f"[serve] alerts ({len(tel['alerts'])}):")
             for a in tel["alerts"]:
                 print(f"  [{a['severity']}] {a['kind']} @ step {a['step']}: "
                       f"{a['message']}")
-        if args.report:
-            print(f"[serve] report -> {tel['report']['html']} + "
-                  f"{tel['report']['prom']}")
         print("[serve] latency percentiles (s):")
         print(f"  {'':<14}{'count':>6}{'p50':>10}{'p95':>10}{'p99':>10}"
               f"{'max':>10}")

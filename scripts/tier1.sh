@@ -21,13 +21,6 @@
 #   aliasing pass, recompile-hazard detector, AST invariant lint — plus
 #   a sanitized drain over every engine configuration via
 #   scripts/analyze.py; any finding fails the run)
-# With the attribution-report smoke:  ./scripts/tier1.sh --report
-#   (runs scripts/report_smoke.py — drains a telemetry-enabled, warmed
-#   engine per config, then checks attribution completeness (the
-#   sched+device+draft+host components reconstruct each step's wall),
-#   lints the Prometheus exposition, schema-checks the single-file HTML
-#   report, and verifies the warmup-only cost-model contract with zero
-#   post-warmup XLA traces; any violation fails the run)
 # With the seeded fault-plan smoke:  ./scripts/tier1.sh --chaos
 #   (runs scripts/chaos_smoke.py — drains a deterministic request mix
 #   clean and under seeded FaultPlans (OutOfPages spike, drafter failure
@@ -40,13 +33,11 @@ cd "$(dirname "$0")/.."
 BENCH_SMOKE=0
 ANALYZE=0
 CHAOS=0
-REPORT=0
 ARGS=()
 for a in "$@"; do
   if [[ "$a" == "--bench-smoke" ]]; then BENCH_SMOKE=1;
   elif [[ "$a" == "--analyze" ]]; then ANALYZE=1;
   elif [[ "$a" == "--chaos" ]]; then CHAOS=1;
-  elif [[ "$a" == "--report" ]]; then REPORT=1;
   else ARGS+=("$a"); fi
 done
 
@@ -67,9 +58,4 @@ fi
 if [[ "$CHAOS" == 1 ]]; then
   PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
     python scripts/chaos_smoke.py
-fi
-
-if [[ "$REPORT" == 1 ]]; then
-  PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
-    python scripts/report_smoke.py
 fi

@@ -119,6 +119,7 @@ def ragged_attention_kernel_call(q: jnp.ndarray, k_pages: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((w, hkv, g, dh), q.dtype),
         interpret=interpret,
+        name="ragged_attn",
     )(row_ids, q_pos, block_tables, q.reshape(w, hkv, g, dh), k_pages,
       v_pages)
     return out.reshape(w, hq, dh)

@@ -246,9 +246,10 @@ def attn_apply(params: dict, x: Stream, ctx: MatmulContext, cfg: ModelConfig, *,
     kv_len_mask = None
     if paged is not None and "row_ids" in paged:
         from repro.kernels.ragged_attn import ragged_attention
-        new_cache = flat_paged_kv_update(
-            kv_cache, k, v, block_tables=paged["block_tables"],
-            row_ids=paged["row_ids"], q_pos=paged["q_pos"])
+        with jax.named_scope("kv_update"):
+            new_cache = flat_paged_kv_update(
+                kv_cache, k, v, block_tables=paged["block_tables"],
+                row_ids=paged["row_ids"], q_pos=paged["q_pos"])
         out = ragged_attention(
             q[0], new_cache["k_pages"], new_cache["v_pages"],
             block_tables=paged["block_tables"], row_ids=paged["row_ids"],
@@ -258,9 +259,10 @@ def attn_apply(params: dict, x: Stream, ctx: MatmulContext, cfg: ModelConfig, *,
                            tp="row")
         return out, new_cache
     if paged is not None:
-        new_cache, k, v, kv_len_mask = paged_kv_update(
-            kv_cache, k, v, block_tables=paged["block_tables"],
-            lens=paged["lens"], new_counts=paged["new_counts"])
+        with jax.named_scope("kv_update"):
+            new_cache, k, v, kv_len_mask = paged_kv_update(
+                kv_cache, k, v, block_tables=paged["block_tables"],
+                lens=paged["lens"], new_counts=paged["new_counts"])
     elif kv_cache is not None:
         # decode: insert this step's K/V at cache_pos, attend over the cache
         kc = jax.lax.dynamic_update_slice(kv_cache["k"], k.astype(kv_cache["k"].dtype),

@@ -229,15 +229,17 @@ class ReproModel:
         last — K bounds the head projection at k+1 however wide the fused
         chunk is); ``None`` reads each row's last valid token.  Returns
         (logits [B, K, V] (K=1 when ``logits_idx`` is None), caches')."""
-        x = embed_apply(params["embed"], token).astype(self.compute_dtype)
-        positions = lens[:, None] + jnp.arange(token.shape[1], dtype=jnp.int32)
-        paged = {"block_tables": block_tables, "lens": lens,
-                 "new_counts": new_counts}
-        logits_at = (jnp.maximum(new_counts - 1, 0) if logits_idx is None
-                     else logits_idx)
-        logits, new_caches, _ = tfm.lm_apply(
-            params, x, self.ctx, self.cfg, self.run, positions=positions,
-            caches=caches, paged=paged, logits_at=logits_at)
+        with jax.named_scope("paged_step"):
+            x = embed_apply(params["embed"], token).astype(self.compute_dtype)
+            positions = lens[:, None] + jnp.arange(token.shape[1],
+                                                   dtype=jnp.int32)
+            paged = {"block_tables": block_tables, "lens": lens,
+                     "new_counts": new_counts}
+            logits_at = (jnp.maximum(new_counts - 1, 0)
+                         if logits_idx is None else logits_idx)
+            logits, new_caches, _ = tfm.lm_apply(
+                params, x, self.ctx, self.cfg, self.run, positions=positions,
+                caches=caches, paged=paged, logits_at=logits_at)
         return logits, new_caches
 
     def flat_decode_step(self, params: dict, caches: dict, token: Array,
@@ -256,13 +258,14 @@ class ReproModel:
         flat positions to read logits at (each row's last position per
         draft slot — fixed K keeps the head projection and the step shape
         static).  Returns (logits [1, K, V], caches')."""
-        x = embed_apply(params["embed"], token).astype(self.compute_dtype)
-        positions = q_pos[None, :]
-        paged = {"block_tables": block_tables, "row_ids": row_ids,
-                 "q_pos": q_pos}
-        logits, new_caches, _ = tfm.lm_apply(
-            params, x, self.ctx, self.cfg, self.run, positions=positions,
-            caches=caches, paged=paged, logits_at=logits_idx[None, :])
+        with jax.named_scope("flat_step"):
+            x = embed_apply(params["embed"], token).astype(self.compute_dtype)
+            paged = {"block_tables": block_tables, "row_ids": row_ids,
+                     "q_pos": q_pos}
+            logits, new_caches, _ = tfm.lm_apply(
+                params, x, self.ctx, self.cfg, self.run,
+                positions=q_pos[None, :], caches=caches, paged=paged,
+                logits_at=logits_idx[None, :])
         return logits, new_caches
 
     def prefill_cache(self, params: dict, batch: dict) -> dict:

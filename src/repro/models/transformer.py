@@ -98,10 +98,11 @@ def block_apply(p: dict, x: Stream, ctx: MatmulContext, cfg: ModelConfig, pos: i
     new_cache: dict = {}
     if t == "attn":
         mix_cache = None if cache is None else cache.get("kv")
-        out, kv = attention.attn_apply(
-            p["mixer"], h, ctx, cfg, positions=positions, causal=causal,
-            kv_cache=mix_cache, cache_pos=cache_pos, keep_packed=keep,
-            paged=paged)
+        with jax.named_scope("attention"):
+            out, kv = attention.attn_apply(
+                p["mixer"], h, ctx, cfg, positions=positions, causal=causal,
+                kv_cache=mix_cache, cache_pos=cache_pos, keep_packed=keep,
+                paged=paged)
         if cache is not None:
             new_cache["kv"] = kv
     elif t == "mamba":
@@ -145,7 +146,9 @@ def block_apply(p: dict, x: Stream, ctx: MatmulContext, cfg: ModelConfig, pos: i
         if cache is not None:
             new_cache.update(cmc)
     else:
-        out2 = mlp_mod.mlp_apply(p["ffn"], h2, ctx, cfg, keep_packed=keep)
+        with jax.named_scope("mlp"):
+            out2 = mlp_mod.mlp_apply(p["ffn"], h2, ctx, cfg,
+                                     keep_packed=keep)
     x = stream_add(x, _as_stream_like(out2, x, ctx))
     return x, (new_cache if cache is not None else None), aux
 
@@ -334,5 +337,6 @@ def lm_apply(params: dict, embeds: Array, ctx: MatmulContext, cfg: ModelConfig,
     elif last_only:
         x = maybe_unpack(x)[:, -1:, :]
         x = maybe_pack(x, ctx)
-    logits = logits_apply(params, x, ctx, cfg)
+    with jax.named_scope("lm_head"):
+        logits = logits_apply(params, x, ctx, cfg)
     return logits, new_caches, aux

@@ -1,10 +1,11 @@
-"""``repro.obs`` — serving observability: lifecycle tracing, streaming
-metrics, performance attribution, anomaly monitors, Perfetto trace
-export, Prometheus/HTML exposition.
+"""``repro.obs`` — serving observability: step-phase spans in the
+profiler's trace, lifecycle tracing, streaming metrics, performance
+attribution, anomaly monitors, Perfetto trace export.
 
-Zero-dependency (stdlib only) and strictly host-side: every event is a
-Python method call timed with ``time.perf_counter()``; nothing here
-touches a jitted code path, a device array, or the token math.  The
+Strictly host-side: every event is a Python method call timed with
+``time.perf_counter()`` (step phases are also
+``jax.profiler.TraceAnnotation`` spans); nothing here touches a jitted
+code path, a device array, or the token math.  The
 standing serving invariants therefore hold by construction — telemetry
 on/off is token-identical, adds zero post-warmup XLA traces, and the
 disabled default (:data:`~repro.obs.telemetry.NULL`) costs one no-op
@@ -37,9 +38,20 @@ event                     meaning
                           (never queued)
 ========================  ==========================================
 
-**Step phases** (per engine step, on the ``engine`` track): a ``step``
-span wrapping ``device`` (jitted forward) and ``draft`` (drafter
-proposal) sub-spans; host planning time is the remainder.
+**Step phases** (per engine step, in the profiler's own trace, on the
+device's clock): ``Engine.step`` marks each phase with
+``telemetry.span(name, **args)``, a ``jax.profiler.TraceAnnotation``
+whether telemetry is on or off.  One ``engine.step`` span holds, in
+order, ``engine.schedule`` (expiry, admission, ``engine.draft``, growth,
+segment planning), ``engine.pack`` (the step's input arrays),
+``engine.dispatch`` (inputs sent and the jitted step enqueued; args
+``step``, ``width``, ``prefill_tokens``, ``decode_tokens``),
+``engine.device_wait`` (until the logits are ready),
+``engine.logits_to_host`` (the copy; arg ``bytes``) and
+``engine.commit`` (picks, cursor advance, cache insert, finishing).
+The flat policy takes all six; the chunked and monolithic policies the
+three around their device calls.  With no profiler session running a
+span costs about a microsecond.
 
 **Component instants**: ``cow`` (copy-on-write page split),
 ``prefix_hit`` / ``prefix_evict`` (prefix cache), ``spec_rollback``
@@ -52,7 +64,7 @@ Streaming metrics
 A :class:`~repro.obs.metrics.MetricsRegistry` of counters, gauges, and
 fixed-bucket geometric histograms (p50/p95/p99 without retaining
 samples): TTFT, ITL, queue wait, e2e latency, and the per-step
-wall/host/device/draft breakdown.  Reset semantics are explicit and
+wall/host/call/draft breakdown.  Reset semantics are explicit and
 documented in :mod:`repro.obs.metrics` — drain-scoped metrics reset
 only via ``Engine.telemetry(reset=True)``; lifetime metrics never.
 ``Engine.telemetry()`` is the one unified view: components' classic
@@ -69,7 +81,7 @@ engine's ladder, from abstract ``lower().compile()`` + XLA cost
 analysis plus an explicit KV-page-gather traffic term — and freezes it
 (the warmup-only contract: the per-step hot path only ever does dict
 lookups).  Each measured step is tagged with its family label(s) and
-its wall split into ``sched + device + draft + host`` — complete by
+its wall split into ``sched + call + draft + host`` — complete by
 construction, the components sum back to the wall (asserted in
 ``tests/test_attrib.py``).  Drain roll-ups report MFU/MBU, padding
 waste (padded-minus-real grid positions priced at the family's
@@ -81,25 +93,13 @@ Anomaly monitors
 ================
 
 :mod:`repro.obs.monitors` runs five host-side online detectors once per
-step — ``step-outlier`` (per-family device time vs rolling median),
+step — ``step-outlier`` (per-family call time vs rolling median),
 ``preempt-storm``, ``prefix-churn``, ``queue-growth``, and ``slo-burn``
 (TTFT/ITL target violation rate) — emitting typed
 :class:`~repro.obs.monitors.Alert`\\ s that land in
 ``Engine.telemetry()["alerts"]``, the ``alerts_emitted`` counter, and
 the ``monitor`` trace track.  One alert per excursion (re-arm on
 clearing), bounded retention.
-
-Exposition formats
-==================
-
-:mod:`repro.obs.export` renders the above without observing anything:
-:func:`~repro.obs.export.prometheus_text` (text format 0.0.4, linted by
-the pure-python :func:`~repro.obs.export.lint_prometheus`) and
-:func:`~repro.obs.export.html_report` (one self-contained file —
-attribution waterfall, per-family table, latency percentiles, alert
-log).  ``Engine.telemetry(report=path)`` writes the ``.html``/``.prom``
-pair; ``scripts/report_smoke.py`` (``tier1.sh --report``) smoke-checks
-both end to end.
 
 Trace file format
 =================
@@ -108,7 +108,7 @@ Chrome ``trace_event`` JSON (the ``{"traceEvents": [...]}`` flavour),
 loadable in Perfetto or ``chrome://tracing``; microsecond timestamps
 relative to recorder birth.  One track (thread) per serving slot plus
 ``engine`` / ``scheduler`` / ``pool`` tracks; ``"X"`` complete spans
-for prefill chunks, decode runs, and step phases; ``"b"``/``"e"``
+for prefill chunks and decode runs; ``"b"``/``"e"``
 async spans for (overlapping) queue waits keyed by rid; ``"i"``
 instants for the transition events above; ``"C"`` counters for pool
 occupancy and scheduler load.  Details in :mod:`repro.obs.trace`.
@@ -116,8 +116,6 @@ occupancy and scheduler load.  Details in :mod:`repro.obs.trace`.
 
 from repro.obs.attrib import (FamilyCost, StepCostModel, build_cost_model,
                               summarize)
-from repro.obs.export import (html_report, lint_prometheus, prometheus_text,
-                              write_report)
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
 from repro.obs.monitors import Alert, Monitors
 from repro.obs.telemetry import NULL, NullTelemetry, Telemetry
@@ -127,6 +125,5 @@ __all__ = [
     "Alert", "Counter", "FamilyCost", "Gauge", "Histogram",
     "MetricsRegistry", "Monitors", "NULL", "NullTelemetry",
     "StepCostModel", "Telemetry", "TraceRecorder", "build_cost_model",
-    "html_report", "lint_prometheus", "prometheus_text", "summarize",
-    "write_report",
+    "summarize",
 ]

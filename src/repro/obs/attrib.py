@@ -26,7 +26,7 @@ launch.
 Per-step attribution then happens entirely on the telemetry side
 (:mod:`repro.obs.telemetry`): each measured step is tagged with the
 family label(s) it executed, its wall time is split into
-``sched + device + draft + host`` (exact by construction — the split is
+``sched + call + draft + host`` (exact by construction — the split is
 derived from the step's own span timestamps, so the components sum to the
 measured wall; asserted within tolerance in ``tests/test_attrib.py``),
 and *padding waste* prices the flat step's ``fill`` in time units:
@@ -185,7 +185,7 @@ def build_cost_model(engine, hw=None) -> StepCostModel:
 
 def fresh_totals() -> dict:
     """A zeroed drain-total accumulator (see :func:`update_aggregates`)."""
-    return {"steps": 0, "wall_s": 0.0, "sched_s": 0.0, "device_s": 0.0,
+    return {"steps": 0, "wall_s": 0.0, "sched_s": 0.0, "call_s": 0.0,
             "draft_s": 0.0, "host_s": 0.0, "predicted_s": 0.0,
             "padding_waste_s": 0.0, "real_tokens": 0, "padded_tokens": 0}
 
@@ -199,17 +199,17 @@ def update_aggregates(tot: dict, fams: Dict[str, dict], rec: dict,
     tot["steps"] += 1
     tot["wall_s"] += rec["wall"]
     tot["sched_s"] += rec["sched"]
-    tot["device_s"] += rec["device"]
+    tot["call_s"] += rec["call"]
     tot["draft_s"] += rec["draft"]
     tot["host_s"] += rec["host"]
-    for label, real, width, dev_s in rec["families"]:
+    for label, real, width, call_s in rec["families"]:
         f = fams.setdefault(label, {
             "steps": 0, "real_tokens": 0, "padded_tokens": 0,
-            "device_s": 0.0, "predicted_s": 0.0, "padding_waste_s": 0.0})
+            "call_s": 0.0, "predicted_s": 0.0, "padding_waste_s": 0.0})
         f["steps"] += 1
         f["real_tokens"] += real
         f["padded_tokens"] += width
-        f["device_s"] += dev_s
+        f["call_s"] += call_s
         fc = cost_model.get(label) if cost_model is not None else None
         if fc is not None:
             f["predicted_s"] += fc.predicted_s
@@ -237,8 +237,8 @@ def finalize_summary(tot: dict, fams: Dict[str, dict],
     fams = {label: dict(f) for label, f in fams.items()}
     for f in fams.values():
         f["fill"] = f["real_tokens"] / max(1, f["padded_tokens"])
-        f["predicted_vs_measured"] = (f["predicted_s"] / f["device_s"]
-                                      if f["device_s"] > 0 else 0.0)
+        f["predicted_vs_measured"] = (f["predicted_s"] / f["call_s"]
+                                      if f["call_s"] > 0 else 0.0)
     wall = tot["wall_s"]
     out = {"totals": dict(tot), "families": fams}
     if cost_model is not None and wall > 0:
@@ -249,7 +249,7 @@ def finalize_summary(tot: dict, fams: Dict[str, dict],
         out["mfu"] = useful_flops / (wall * cost_model.peak_flops)
         out["mbu"] = modelled_bytes / (wall * cost_model.hbm_bw)
         out["padding_waste_ratio"] = (tot["padding_waste_s"]
-                                      / max(tot["device_s"], 1e-12))
+                                      / max(tot["call_s"], 1e-12))
         out["achieved_tokens_per_s"] = tot["real_tokens"] / wall
         out["roofline_tokens_per_s"] = (
             tot["real_tokens"] / tot["predicted_s"]

@@ -14,11 +14,12 @@ one without (checked in ``tests/test_attrib.py``).
 Monitors (all windows are step-indexed, sizes are constructor knobs):
 
 ``step-outlier``
-    Per-family step device time vs the family's rolling median: a step
-    slower than ``outlier_factor`` x median over a warm window (>=
-    ``outlier_min`` samples) is an anomaly — a GC stall, a page-copy
-    storm, a noisy neighbour.  Per family, not global, so a legitimate
-    wide-prefill step never shadows a slow decode step.
+    Per-family step call time (dispatch to logits on the host) vs the
+    family's rolling median: a step slower than ``outlier_factor`` x
+    median over a warm window (>= ``outlier_min`` samples) is an
+    anomaly — a GC stall, a page-copy storm, a noisy neighbour.  Per
+    family, not global, so a legitimate wide-prefill step never shadows
+    a slow decode step.
 ``preempt-storm``
     Preemptions over the last ``window`` steps above ``storm_limit``:
     the pool is thrashing (working set over capacity) and throughput is
@@ -100,7 +101,7 @@ class Monitors:
         self.burn_rate = burn_rate
         self.alerts: Deque[Alert] = deque(maxlen=max_alerts)
         self._step = 0
-        self._fam_dev: Dict[str, Deque[float]] = {}
+        self._fam_call: Dict[str, Deque[float]] = {}
         self._preempt: Deque[int] = deque(maxlen=window)
         self._evict: Deque[int] = deque(maxlen=window)
         self._hits: Deque[int] = deque(maxlen=window)
@@ -125,7 +126,7 @@ class Monitors:
             self._itl_viol.append(v > self.slo_itl_s)
 
     def observe_step(self, *, t: float, scheduler, telemetry,
-                     families, device_s: float) -> List[Alert]:
+                     families, call_s: float) -> List[Alert]:
         """Run every rule against this step; returns the alerts that
         fired *this step* (already appended to ``self.alerts``)."""
         self._step += 1
@@ -139,20 +140,20 @@ class Monitors:
         # per-family step-time outlier vs the rolling median.  The
         # current sample joins the window only after the comparison, so
         # a single spike cannot drag its own baseline up.
-        for label, real, width, dev_s in families:
-            win = self._fam_dev.setdefault(
+        for label, real, width, step_s in families:
+            win = self._fam_call.setdefault(
                 label, deque(maxlen=self.window))
             if len(win) >= self.outlier_min:
                 med = _median(win)
                 bound = self.outlier_factor * med
-                if med > 0 and dev_s > bound:
-                    self._fire("step-outlier", "warn", t, dev_s, bound,
-                               f"{label}: device {dev_s * 1e3:.2f}ms > "
+                if med > 0 and step_s > bound:
+                    self._fire("step-outlier", "warn", t, step_s, bound,
+                               f"{label}: call {step_s * 1e3:.2f}ms > "
                                f"{self.outlier_factor:.0f}x rolling median "
                                f"{med * 1e3:.2f}ms")
-                elif dev_s <= bound:
+                elif step_s <= bound:
                     self._armed["step-outlier"] = True
-            win.append(dev_s)
+            win.append(step_s)
 
         # preemption storm: window sum of per-step preemption deltas
         cur = scheduler.num_preemptions

@@ -4,15 +4,19 @@ One :class:`Telemetry` instance per engine, threaded by reference into
 the scheduler, KV pool, prefix cache, drafter, and fault layer.  Every
 instrumentation point in the serving stack is a single method call on
 this object; the default is the module-level :data:`NULL` —
-a :class:`NullTelemetry` whose methods are all no-ops and whose
-``clock()`` never reads the time — so a telemetry-off engine pays one
+a :class:`NullTelemetry` whose methods are all no-ops but
+:meth:`~NullTelemetry.span` — so a telemetry-off engine pays one
 attribute load plus one no-op call per event and takes **no** clock
 reads on the hot path.
 
-Everything here is host-side Python over ``time.perf_counter()``; no
-method ever touches a jitted code path or a device array, which is how
-the on/off token-identity and zero-retrace invariants hold by
-construction (checked end-to-end in ``tests/test_obs.py``).
+The engine marks each phase of a step with :meth:`NullTelemetry.span`,
+a ``jax.profiler.TraceAnnotation``: with a profiler session running the
+phase lands in the profiler's trace, on the device's clock; without one
+it costs about a microsecond.  Everything else here is host-side Python
+over ``time.perf_counter()``; no method ever touches a jitted code path
+or a device array, which is how the on/off token-identity and
+zero-retrace invariants hold by construction (checked end-to-end in
+``tests/test_obs.py``).
 
 Per-request event log: when telemetry is live, every lifecycle event is
 also appended to ``request.obs_events`` as ``(label, t_seconds)`` — the
@@ -25,9 +29,12 @@ and :mod:`repro.obs.metrics` for the drain-vs-lifetime reset contract.
 
 from __future__ import annotations
 
+import contextlib
 import time
 from collections import deque
 from typing import Deque, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.attrib import finalize_summary, fresh_totals as _fresh_totals, \
     update_aggregates
@@ -40,8 +47,8 @@ __all__ = ["NullTelemetry", "Telemetry", "NULL"]
 
 class NullTelemetry:
     """The telemetry-off stand-in: every event method is an explicit
-    no-op and :meth:`clock` returns 0.0 without reading the time — the
-    disabled path costs one method call, never a syscall."""
+    no-op, and :meth:`span` returns a bare profiler annotation — the
+    disabled path costs one method call, never a clock read."""
 
     enabled = False
     registry = None
@@ -49,9 +56,6 @@ class NullTelemetry:
     cost_model = None
     monitors = None
     alerts: tuple = ()
-
-    def clock(self) -> float:
-        return 0.0
 
     # -- lifecycle -----------------------------------------------------
     def request_queued(self, req) -> None: pass
@@ -66,9 +70,13 @@ class NullTelemetry:
     def request_shed(self, req, kind) -> None: pass
 
     # -- step phases ---------------------------------------------------
+    def span(self, name: str, **args):
+        """A phase of the engine step, as a context manager: a
+        ``TraceAnnotation`` named ``name`` whose keyword ``args`` become
+        the event's stats in the profiler's trace."""
+        return TraceAnnotation(name, **args)
+
     def step_begin(self) -> None: pass
-    def device_span(self, t0) -> None: pass
-    def draft_span(self, t0) -> None: pass
     def step_family(self, label, real, width) -> None: pass
     def step_end(self, scheduler, pool, finished, now=None) -> None: pass
 
@@ -111,15 +119,17 @@ class Telemetry(NullTelemetry):
         self.h_queue_wait = r.histogram("queue_wait_s")
         self.h_e2e = r.histogram("e2e_s")
         # per-step phase breakdown (seconds).  Attribution completeness:
-        # wall == sched + device + draft + host by construction (the
-        # split is derived from the step's own span timestamps; asserted
-        # within tolerance in tests/test_attrib.py).  ``sched`` is the
-        # host time before the first device/draft span (admission, page
-        # growth, chunk planning); ``host`` is the interleaved + post-
-        # device remainder (verify loop, numpy staging).
+        # wall == sched + call + draft + host by construction (the split
+        # is derived from the step's own span timestamps; asserted within
+        # tolerance in tests/test_attrib.py).  ``call`` is host time from
+        # a jitted step's dispatch to its logits on the host (device work,
+        # the wait for it and the copy); ``sched`` is the host time before
+        # the first call/draft span (admission, page growth, chunk
+        # planning); ``host`` is the interleaved + post-call remainder
+        # (input packing, the verify loop).
         self.h_step_wall = r.histogram("step_wall_s")
         self.h_step_host = r.histogram("step_host_s")
-        self.h_step_device = r.histogram("step_device_s")
+        self.h_step_call = r.histogram("step_call_s")
         self.h_step_draft = r.histogram("step_draft_s")
         self.h_step_sched = r.histogram("step_sched_s")
         # padding waste: padded-minus-real grid positions priced at the
@@ -159,18 +169,19 @@ class Telemetry(NullTelemetry):
         self._live: Dict[int, dict] = {}
         # current step's accumulators
         self._step_t0: Optional[float] = None
-        self._dev_s = 0.0
+        self._call_s = 0.0
         self._draft_s = 0.0
-        self._dev_window = None        # (t0, t1) of the latest device call
+        self._call_t0 = 0.0            # start of the open call's dispatch
+        self._call_window = None       # (t0, t1) of the latest call
         # attribution state (repro.obs.attrib): the warmup-frozen cost
         # model, this step's family tags, a bounded window of per-step
-        # attribution records (tests + the HTML waterfall), and running
+        # attribution records (read by tests), and running
         # per-family aggregates that survive the window bound
         self.cost_model = None
         self.monitors = Monitors()
-        self._families: list = []      # (label, real, width, dev_s) tags
+        self._families: list = []      # (label, real, width, call_s) tags
         self._first_span_t0: Optional[float] = None
-        self._last_dev = 0.0
+        self._last_call = 0.0
         self.step_records: Deque[dict] = deque(maxlen=4096)
         self._agg_tot: dict = _fresh_totals()
         self._agg_fams: Dict[str, dict] = {}
@@ -180,9 +191,6 @@ class Telemetry(NullTelemetry):
         return self.monitors.alerts
 
     # ------------------------------------------------------------------
-    def clock(self) -> float:
-        return self._clock()
-
     def _mark(self, req, label: str, t: float) -> None:
         req.obs_events.append((label, t))
 
@@ -224,7 +232,7 @@ class Telemetry(NullTelemetry):
         t = self._clock()
         self._mark(req, "prefill_chunk", t)
         if self.tracer:
-            w = self._dev_window or (t, t)
+            w = self._call_window or (t, t)
             self.tracer.complete(self._slot_track(req), "prefill",
                                  w[0], w[1],
                                  args={"rid": req.rid, "tokens": n,
@@ -331,39 +339,47 @@ class Telemetry(NullTelemetry):
                                 args={"rid": req.rid, "kind": kind})
 
     # -- step phases ---------------------------------------------------
+    @contextlib.contextmanager
+    def span(self, name: str, **args):
+        """:meth:`NullTelemetry.span`, plus the host-clock accounting of
+        the phases it knows: a call runs from ``engine.dispatch``'s start
+        to ``engine.logits_to_host``'s end, a draft is ``engine.draft``."""
+        with TraceAnnotation(name, **args):
+            t0 = self._clock()
+            yield
+            t1 = self._clock()
+        if name == "engine.dispatch":
+            self._call_t0 = t0
+        elif name == "engine.logits_to_host":
+            self._span_done(self._call_t0, t1, call=True)
+        elif name == "engine.draft":
+            self._span_done(t0, t1, call=False)
+
+    def _span_done(self, t0: float, t1: float, *, call: bool) -> None:
+        if self._first_span_t0 is None:
+            self._first_span_t0 = t0
+        if call:
+            self._call_s += t1 - t0
+            self._call_window = (t0, t1)
+            self._last_call = t1 - t0
+        else:
+            self._draft_s += t1 - t0
+
     def step_begin(self) -> None:
         self._step_t0 = self._clock()
-        self._dev_s = 0.0
+        self._call_s = 0.0
         self._draft_s = 0.0
-        self._dev_window = None
+        self._call_window = None
         self._families = []
         self._first_span_t0 = None
-        self._last_dev = 0.0
-
-    def device_span(self, t0: float) -> None:
-        t1 = self._clock()
-        self._dev_s += t1 - t0
-        self._dev_window = (t0, t1)
-        self._last_dev = t1 - t0
-        if self._first_span_t0 is None:
-            self._first_span_t0 = t0
-        if self.tracer:
-            self.tracer.complete("engine", "device", t0, t1)
-
-    def draft_span(self, t0: float) -> None:
-        t1 = self._clock()
-        self._draft_s += t1 - t0
-        if self._first_span_t0 is None:
-            self._first_span_t0 = t0
-        if self.tracer:
-            self.tracer.complete("engine", "draft", t0, t1)
+        self._last_call = 0.0
 
     def step_family(self, label: str, real: int, width: int) -> None:
-        """Tag the device span just recorded with its compiled shape
-        family (called by the engine right after ``device_span``):
-        ``real`` useful tokens rode a ``width``-position grid."""
+        """Tag the call just recorded with its compiled shape family
+        (called by the engine right after the call's spans): ``real``
+        useful tokens rode a ``width``-position grid."""
         self._families.append((label, int(real), int(width),
-                               self._last_dev))
+                               self._last_call))
 
     def step_end(self, scheduler, pool, finished, now=None) -> None:
         t1 = self._clock()
@@ -411,28 +427,28 @@ class Telemetry(NullTelemetry):
         t0 = self._step_t0 if self._step_t0 is not None else t1
         wall = t1 - t0
         # wall decomposition — complete by construction: ``sched`` is
-        # host time before the first device/draft span, ``host`` is the
+        # host time before the first call/draft span, ``host`` is the
         # remainder after subtracting the measured spans, so the four
         # components sum back to wall exactly (up to float rounding;
         # asserted in tests/test_attrib.py)
         first = self._first_span_t0
         sched = min(max(0.0, (first if first is not None else t1) - t0),
                     wall)
-        host = max(0.0, wall - sched - self._dev_s - self._draft_s)
+        host = max(0.0, wall - sched - self._call_s - self._draft_s)
         waste = 0.0
         if self.cost_model is not None:
-            for label, real, width, _dev in self._families:
+            for label, real, width, _call in self._families:
                 fc = self.cost_model.get(label)
                 if fc is not None:
                     waste += (width - real) * fc.per_token_s
         self.c_steps.inc()
         self.h_step_wall.observe(wall)
         self.h_step_host.observe(host)
-        self.h_step_device.observe(self._dev_s)
+        self.h_step_call.observe(self._call_s)
         self.h_step_draft.observe(self._draft_s)
         self.h_step_sched.observe(sched)
         self.h_step_waste.observe(waste)
-        rec = {"wall": wall, "sched": sched, "device": self._dev_s,
+        rec = {"wall": wall, "sched": sched, "call": self._call_s,
                "draft": self._draft_s, "host": host,
                "families": tuple(self._families)}
         self.step_records.append(rec)
@@ -440,18 +456,13 @@ class Telemetry(NullTelemetry):
                           self.cost_model)
         alerts = self.monitors.observe_step(
             t=t1, scheduler=scheduler, telemetry=self,
-            families=self._families, device_s=self._dev_s)
+            families=self._families, call_s=self._call_s)
         for a in alerts:
             self.c_alerts.inc()
             if self.tracer:
                 self.tracer.instant("monitor", f"alert:{a.kind}", a.t,
                                     args=a.to_dict())
         if self.tracer:
-            self.tracer.complete(
-                "engine", "step", t0, t1,
-                args={"running": len(running),
-                      "finished": len(finished),
-                      "families": [f[0] for f in self._families]})
             if pool is not None:
                 self.tracer.counter("pool", "pages",
                                     {"used": pool.num_used,

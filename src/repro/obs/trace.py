@@ -6,8 +6,7 @@ JSON flavour: ``{"traceEvents": [...]}``).  Events used here:
 
 - ``"X"`` **complete** spans — a named interval with ``ts`` + ``dur``
   (microseconds).  Used for everything that nests cleanly on one track:
-  per-slot prefill chunks and decode runs, per-step ``step`` spans and
-  their ``device`` / ``draft`` sub-spans on the engine track.
+  per-slot prefill chunks and decode runs.
 - ``"b"`` / ``"e"`` **async** spans — id-matched begin/end pairs that may
   overlap on a track.  Used for queue-wait episodes on the scheduler
   track (many requests wait concurrently) — ``cat`` + ``id`` pair them.

@@ -499,7 +499,7 @@ class Engine:
             }
         return out
 
-    def telemetry(self, *, reset: bool = False, report=None) -> dict:
+    def telemetry(self, *, reset: bool = False) -> dict:
         """The unified observability view (continuous engine):
 
         - ``components`` — the classic per-component :meth:`stats` tree
@@ -520,11 +520,6 @@ class Engine:
         - ``alerts`` — the monitor bank's typed findings
           (:mod:`repro.obs.monitors`), as dicts, newest last.
 
-        ``report="/path/base"`` additionally writes ``base.html`` (the
-        single-file attribution report) and ``base.prom`` (Prometheus
-        text exposition) via :func:`repro.obs.export.write_report`, and
-        returns the paths under ``"report"``.
-
         ``reset=True`` zeroes the **drain-scoped registry metrics and
         attribution aggregates only**, after the snapshot is taken — the
         explicit per-drain reset (see :mod:`repro.obs.metrics`); nothing
@@ -541,11 +536,6 @@ class Engine:
             "attribution": obs.attribution_summary(),
             "alerts": [a.to_dict() for a in obs.alerts],
         }
-        if report is not None:
-            assert obs.enabled, \
-                "telemetry(report=...) needs a live telemetry engine"
-            from repro.obs.export import write_report
-            out["report"] = write_report(obs, report)
         if reset:
             obs.reset_drain()
         return out
@@ -561,19 +551,35 @@ class Engine:
         including requests shed at admission, cancelled via
         :meth:`cancel`, and (when ``now`` carries a clock) requests whose
         ``deadline_s``/``max_queue_s`` elapsed, with finish reasons
-        ``rejected``/``cancelled``/``timeout``/``error``."""
+        ``rejected``/``cancelled``/``timeout``/``error``.
+
+        The step is one ``engine.step`` span of the telemetry's
+        (:meth:`repro.obs.telemetry.NullTelemetry.span`), its phases
+        spans inside it."""
         t0 = time.perf_counter()
-        self.obs.step_begin()
-        finished = list(self._finished_oob)      # shed/cancelled since
-        self._finished_oob.clear()               # the previous step
+        with self.obs.span("engine.step"):
+            self.obs.step_begin()
+            if self.flat:
+                return self._step_flat(t0, now, greedy, seed)
+            finished = self._take_finished(now)
+            if self.chunked:
+                finished.extend(self._step_chunked(now, greedy, seed))
+            else:
+                finished.extend(self._step_monolithic(now, greedy, seed))
+            self._end_step(t0, now, finished)
+            return finished
+
+    def _take_finished(self, now) -> List[Request]:
+        """Requests shed or cancelled since the previous step, and those
+        whose deadlines ``now`` passed."""
+        finished = list(self._finished_oob)
+        self._finished_oob.clear()
         if now is not None:
             finished.extend(self.scheduler.expire(now))
-        if self.flat:
-            finished.extend(self._step_flat(now, greedy, seed))
-        elif self.chunked:
-            finished.extend(self._step_chunked(now, greedy, seed))
-        else:
-            finished.extend(self._step_monolithic(now, greedy, seed))
+        return finished
+
+    def _end_step(self, t0: float, now, finished: List[Request]) -> None:
+        """The step's bookkeeping once its picks are made."""
         # idle ticks (an online replay polling before the next arrival) do
         # no work and must not dilute the per-step stats
         if self.scheduler.running or finished:
@@ -591,7 +597,6 @@ class Engine:
             if self.drafter is not None:
                 self.drafter.forget(req.rid)
         self.obs.step_end(self.scheduler, self.pool, finished, now=now)
-        return finished
 
     def _watchdog(self, now) -> None:
         """A step that admitted, advanced and finished nothing while
@@ -672,9 +677,9 @@ class Engine:
                 self._fill_decode_row(slot, req, neff[slot], drafts,
                                       token, lens, counts, bt, idx)
             self._active_rows += len(running)
-            td = self.obs.clock()
-            rows = self._run_paged(token, bt, lens, counts, idx)
-            self.obs.device_span(td)
+            rows = self._run_paged(token, bt, lens, counts, idx,
+                                   prefill_tokens=0,
+                                   decode_tokens=int(counts.sum()))
             self.obs.step_family(
                 f"verify[{b},{s}]" if spec else f"decode[{b},1]",
                 int(counts.sum()), b * s)
@@ -749,9 +754,9 @@ class Engine:
         assert total_new <= max(self.token_budget, ndecode)
         self._active_rows += int((counts > 0).sum())
         self._mixed_steps += int(use_chunk)
-        td = self.obs.clock()
-        rows = self._run_paged(token, bt, lens, counts, idx)
-        self.obs.device_span(td)
+        rows = self._run_paged(token, bt, lens, counts, idx,
+                               prefill_tokens=total_new - ndecode,
+                               decode_tokens=ndecode)
         self.obs.step_family(f"chunk[{b},{s}]" + ("/verify" if spec else ""),
                              total_new, b * s)
         for slot, req in list(running.items()):
@@ -791,7 +796,8 @@ class Engine:
                     finished.append(req)
         return finished
 
-    def _step_flat(self, now, greedy: bool, seed: int) -> List[Request]:
+    def _step_flat(self, t0: float, now, greedy: bool,
+                   seed: int) -> List[Request]:
         """The flat token-level step (vLLM/Sarathi-style flat batching; the
         paper's fixed-shape-grid argument at token granularity).  One
         ``[1, W]`` stream — ``W`` from a geometric ladder over the token
@@ -805,106 +811,141 @@ class Engine:
         (admission, growth, chunk planning, stalls, preemption) is byte-
         identical to the dense chunked step; only the layout of the fed
         tokens changes, and outputs stay token-identical to both the dense
-        and monolithic policies (asserted by tests/test_flat_step.py)."""
+        and monolithic policies (asserted by tests/test_flat_step.py).
+
+        Its phases are the spans ``engine.schedule``, ``engine.pack``,
+        the three of :meth:`_call`, and ``engine.commit``, which ends
+        with the step's bookkeeping (:meth:`_end_step`)."""
         sched = self.scheduler
-        finished: List[Request] = []
-        sched.admit(now)
-        drafts = self._draft_and_grow()
-        running = sched.running
-        if not running:
-            return finished
-        neff = self._grant_drafts(running, drafts)
-        decode_counts = {s: n for s, n in neff.items() if n > 0}
-        segs = sched.plan_segments(decode_counts, self.token_budget)
-        total = sum(n for _, _, n in segs)
-        if total == 0:
-            self._watchdog_trips += 1
-            raise StallError(
-                "flat step scheduled zero tokens with live slots: " +
-                ", ".join(f"rid {r.rid} ({r.status}, cursor "
-                          f"{r.prefill_cursor}/{r.prompt_len}, len {r.len})"
-                          for r in running.values()))
-        ndecode = sum(decode_counts.values())
-        # decodes (and their drafts) are unconditional; only prefill
-        # tokens are budget-capped — token-exact, not shape-limited
-        assert total <= max(self.token_budget, ndecode)
-        w = self._flat_shape(total)
-        spec = any(n > 1 for n in decode_counts.values())
-        k1 = self.spec_tokens + 1 if spec else 1
-        token = np.zeros((1, w), np.int32)
-        row_ids = np.full((w,), -1, np.int32)
-        q_pos = np.zeros((w,), np.int32)
-        bt = np.zeros((self.slots, self.max_pages), np.int32)
-        idx = np.zeros((self.slots * k1,), np.int32)
-        pos = 0
+        obs = self.obs
+        segs = []
+        with obs.span("engine.schedule"):
+            finished = self._take_finished(now)
+            sched.admit(now)
+            drafts = self._draft_and_grow()
+            running = sched.running
+            if running:
+                neff = self._grant_drafts(running, drafts)
+                decode_counts = {s: n for s, n in neff.items() if n > 0}
+                segs = sched.plan_segments(decode_counts, self.token_budget)
+                total = sum(n for _, _, n in segs)
+                if total == 0:
+                    self._watchdog_trips += 1
+                    raise StallError(
+                        "flat step scheduled zero tokens with live slots: "
+                        + ", ".join(f"rid {r.rid} ({r.status}, cursor "
+                                    f"{r.prefill_cursor}/{r.prompt_len}, "
+                                    f"len {r.len})"
+                                    for r in running.values()))
+                ndecode = sum(decode_counts.values())
+                # decodes (and their drafts) are unconditional; only
+                # prefill tokens are budget-capped — token-exact, not
+                # shape-limited
+                assert total <= max(self.token_budget, ndecode)
+                prefill = sum(n for _, kind, n in segs if kind == "prefill")
         segrefs = []
-        for slot, kind, n in segs:
-            req = running[slot]
-            if kind == "decode":
-                token[0, pos] = req.out_tokens[-1]
-                if n > 1:
-                    token[0, pos + 1:pos + n] = drafts[slot]
-                q_pos[pos:pos + n] = req.len + np.arange(n)
-            else:
-                cur = req.prefill_cursor
-                token[0, pos:pos + n] = req.prompt[cur:cur + n]
-                q_pos[pos:pos + n] = cur + np.arange(n)
-            row_ids[pos:pos + n] = slot
-            bt[slot] = req.pages.block_row(self.max_pages)
-            # decode rows read logits after every fed position (clamped to
-            # their own width); prefill rows read their last chunk token
-            if kind == "decode":
-                idx[slot * k1:(slot + 1) * k1] = \
-                    pos + np.minimum(np.arange(k1), n - 1)
-            else:
-                idx[slot * k1:(slot + 1) * k1] = pos + n - 1
-            segrefs.append((slot, kind, n, req))
-            pos += n
-        self._active_rows += len(segrefs)
-        self._mixed_steps += int(any(kind == "prefill"
-                                     for _, kind, _ in segs))
-        self._flat_steps += 1
-        self._flat_tokens += total
-        self._flat_width += w
-        td = self.obs.clock()
-        rows = self._run_flat(token, bt, row_ids, q_pos, idx)
-        self.obs.device_span(td)
-        self.obs.step_family(f"flat[1,{w}]/k{k1}", total, w)
-        rows = rows.reshape(self.slots, k1, -1)
-        for slot, kind, n, req in segrefs:
-            if kind == "decode":
-                self._verify_decode_row(req, drafts.get(slot, []),
-                                        rows[slot], n, greedy, seed, finished)
-                continue
-            if self.nan_guard and not np.isfinite(rows[slot]).all():
-                self._quarantine(req, finished)  # before the cache insert
-                continue
-            req.prefill_cursor += n
-            req.len = req.prefill_cursor
-            req.chunk_steps += 1
-            self._prefill_tokens += n
-            self.obs.request_prefill_chunk(req, n)
-            if self.prefix_cache is not None:
-                self.prefix_cache.insert(req.prompt, req.pages.pages,
-                                         req.prefill_cursor)
-            if req.prefill_cursor < req.prompt_len:
-                continue                  # more chunks to come
-            req.status = "running"
-            self.obs.request_prefill_done(req)
-            req.out_tokens.append(
-                self._pick(rows[slot, 0], req, greedy, seed))
-            if req.done():
-                sched.finish(req)
-                finished.append(req)
+        if segs:
+            with obs.span("engine.pack"):
+                w = self._flat_shape(total)
+                spec = any(n > 1 for n in decode_counts.values())
+                k1 = self.spec_tokens + 1 if spec else 1
+                token = np.zeros((1, w), np.int32)
+                row_ids = np.full((w,), -1, np.int32)
+                q_pos = np.zeros((w,), np.int32)
+                bt = np.zeros((self.slots, self.max_pages), np.int32)
+                idx = np.zeros((self.slots * k1,), np.int32)
+                pos = 0
+                for slot, kind, n in segs:
+                    req = running[slot]
+                    if kind == "decode":
+                        token[0, pos] = req.out_tokens[-1]
+                        if n > 1:
+                            token[0, pos + 1:pos + n] = drafts[slot]
+                        q_pos[pos:pos + n] = req.len + np.arange(n)
+                    else:
+                        cur = req.prefill_cursor
+                        token[0, pos:pos + n] = req.prompt[cur:cur + n]
+                        q_pos[pos:pos + n] = cur + np.arange(n)
+                    row_ids[pos:pos + n] = slot
+                    bt[slot] = req.pages.block_row(self.max_pages)
+                    # decode rows read logits after every fed position
+                    # (clamped to their own width); prefill rows read
+                    # their last chunk token
+                    if kind == "decode":
+                        idx[slot * k1:(slot + 1) * k1] = \
+                            pos + np.minimum(np.arange(k1), n - 1)
+                    else:
+                        idx[slot * k1:(slot + 1) * k1] = pos + n - 1
+                    segrefs.append((slot, kind, n, req))
+                    pos += n
+                self._active_rows += len(segrefs)
+                self._mixed_steps += int(prefill > 0)
+                self._flat_steps += 1
+                self._flat_tokens += total
+                self._flat_width += w
+            rows = self._run_flat(token, bt, row_ids, q_pos, idx,
+                                  prefill_tokens=prefill,
+                                  decode_tokens=total - prefill)
+        with obs.span("engine.commit"):
+            if segrefs:
+                obs.step_family(f"flat[1,{w}]/k{k1}", total, w)
+                rows = rows.reshape(self.slots, k1, -1)
+            for slot, kind, n, req in segrefs:
+                if kind == "decode":
+                    self._verify_decode_row(req, drafts.get(slot, []),
+                                            rows[slot], n, greedy, seed,
+                                            finished)
+                    continue
+                if self.nan_guard and not np.isfinite(rows[slot]).all():
+                    self._quarantine(req, finished)  # before the insert
+                    continue
+                req.prefill_cursor += n
+                req.len = req.prefill_cursor
+                req.chunk_steps += 1
+                self._prefill_tokens += n
+                obs.request_prefill_chunk(req, n)
+                if self.prefix_cache is not None:
+                    self.prefix_cache.insert(req.prompt, req.pages.pages,
+                                             req.prefill_cursor)
+                if req.prefill_cursor < req.prompt_len:
+                    continue              # more chunks to come
+                req.status = "running"
+                obs.request_prefill_done(req)
+                req.out_tokens.append(
+                    self._pick(rows[slot, 0], req, greedy, seed))
+                if req.done():
+                    sched.finish(req)
+                    finished.append(req)
+            self._end_step(t0, now, finished)
         return finished
 
-    def _run_flat(self, token, bt, row_ids, q_pos, idx) -> np.ndarray:
+    def _run_flat(self, token, bt, row_ids, q_pos, idx, *,
+                  prefill_tokens: int, decode_tokens: int) -> np.ndarray:
         """One flat step; returns logits [K_out, V] at the flat ``idx``
-        positions (K_out = slots * (spec_tokens+1 or 1))."""
-        logits, self.caches = self._flat_step(
-            self.params, self.caches, jnp.asarray(token), jnp.asarray(bt),
-            jnp.asarray(row_ids), jnp.asarray(q_pos), jnp.asarray(idx))
-        return np.asarray(logits)[0]
+        positions (K_out = slots * (spec_tokens+1 or 1)).  The token
+        counts label the ``engine.dispatch`` span."""
+        logits, self.caches = self._call(
+            self._flat_step, self.caches, (token, bt, row_ids, q_pos, idx),
+            width=token.shape[1], prefill_tokens=prefill_tokens,
+            decode_tokens=decode_tokens)
+        return logits[0]
+
+    def _call(self, step_fn, caches, inputs, **args):
+        """Run the jitted ``step_fn(params, caches, *inputs)`` on host
+        ``inputs`` and bring its logits to the host, in three spans:
+        ``engine.dispatch`` (inputs sent, the program enqueued; ``args``
+        and the engine's step count label it), ``engine.device_wait``
+        (until the logits are ready) and ``engine.logits_to_host`` (the
+        copy alone).  Returns (host logits, new caches)."""
+        obs = self.obs
+        with obs.span("engine.dispatch", step=self._steps, **args):
+            logits, caches = step_fn(
+                self.params, caches,
+                *(None if a is None else jnp.asarray(a) for a in inputs))
+        with obs.span("engine.device_wait"):
+            jax.block_until_ready(logits)
+        with obs.span("engine.logits_to_host", bytes=logits.nbytes):
+            return np.asarray(logits), caches
 
     def _flat_shapes(self) -> List[int]:
         """The flat step's geometric width ladder, descending: the token
@@ -950,41 +991,42 @@ class Engine:
         drafter degrades throughput, never correctness or liveness."""
         if self.drafter is None or self._spec_disabled:
             return {}
-        t0 = time.perf_counter()
-        jobs, slot_of = [], {}
-        for slot, req in self.scheduler.running.items():
-            if req.status != "running":
-                continue
-            k = min(self.spec_tokens, req.max_new - len(req.out_tokens) - 1)
-            if k <= 0:
-                continue
-            jobs.append((req, k))
-            slot_of[req.rid] = slot
-        drafts = {}
-        if jobs:
-            # one batched call for the whole step's rows — a model-backed
-            # drafter runs one [slots, 1] step per draft position instead
-            # of k sequential [1, 1] steps per row (Drafter.propose_all;
-            # the base class degenerates to the per-row loop)
-            try:
-                proposals = self.drafter.propose_all(jobs)
-            except Exception:
-                self._drafter_errors += 1
-                self._drafter_fail_streak += 1
-                self.obs.drafter_error()
-                if self._drafter_fail_streak >= self._drafter_fail_limit:
-                    self._spec_disabled = True
-                    self._spec_auto_disables += 1
-                proposals = {}
-            else:
-                self._drafter_fail_streak = 0
-            for req, k in jobs:
-                d = [int(t) for t in proposals.get(req.rid, [])][:k]
-                if d:
-                    drafts[slot_of[req.rid]] = d
-        self._draft_time += time.perf_counter() - t0
-        self.obs.draft_span(t0)
-        return drafts
+        with self.obs.span("engine.draft"):
+            t0 = time.perf_counter()
+            jobs, slot_of = [], {}
+            for slot, req in self.scheduler.running.items():
+                if req.status != "running":
+                    continue
+                k = min(self.spec_tokens,
+                        req.max_new - len(req.out_tokens) - 1)
+                if k <= 0:
+                    continue
+                jobs.append((req, k))
+                slot_of[req.rid] = slot
+            drafts = {}
+            if jobs:
+                # one batched call for the whole step's rows — a model-backed
+                # drafter runs one [slots, 1] step per draft position instead
+                # of k sequential [1, 1] steps per row (Drafter.propose_all;
+                # the base class degenerates to the per-row loop)
+                try:
+                    proposals = self.drafter.propose_all(jobs)
+                except Exception:
+                    self._drafter_errors += 1
+                    self._drafter_fail_streak += 1
+                    self.obs.drafter_error()
+                    if self._drafter_fail_streak >= self._drafter_fail_limit:
+                        self._spec_disabled = True
+                        self._spec_auto_disables += 1
+                    proposals = {}
+                else:
+                    self._drafter_fail_streak = 0
+                for req, k in jobs:
+                    d = [int(t) for t in proposals.get(req.rid, [])][:k]
+                    if d:
+                        drafts[slot_of[req.rid]] = d
+            self._draft_time += time.perf_counter() - t0
+            return drafts
 
     def _draft_and_grow(self):
         """Propose drafts, then grow with the per-row ``1 + draft length``
@@ -1036,14 +1078,16 @@ class Engine:
             neff[slot] = n
         return neff
 
-    def _run_paged(self, token, bt, lens, counts, idx) -> np.ndarray:
+    def _run_paged(self, token, bt, lens, counts, idx, *,
+                   prefill_tokens: int, decode_tokens: int) -> np.ndarray:
         """One fused paged step; returns per-row logits [B, K, V] (K = 1
-        without speculation)."""
-        logits, self.caches = self._paged_step(
-            self.params, self.caches, jnp.asarray(token), jnp.asarray(bt),
-            jnp.asarray(lens), jnp.asarray(counts),
-            None if idx is None else jnp.asarray(idx))
-        return np.asarray(logits)
+        without speculation).  The token counts label the
+        ``engine.dispatch`` span."""
+        logits, self.caches = self._call(
+            self._paged_step, self.caches, (token, bt, lens, counts, idx),
+            width=token.size, prefill_tokens=prefill_tokens,
+            decode_tokens=decode_tokens)
+        return logits
 
     def _verify_decode_row(self, req: Request, drafts: List[int],
                            rows_slot: np.ndarray, n: int, greedy: bool,
@@ -1264,13 +1308,12 @@ class Engine:
         token[0, :n] = req.prompt[start:]
         bt = req.pages.block_row(self.max_pages)[None]
         view = prefill_view(self.caches, fresh_slot_states(self.caches))
-        td = self.obs.clock()
-        logits, updated = self._paged_step(
-            self.params, view, jnp.asarray(token), jnp.asarray(bt),
-            jnp.full((1,), start, jnp.int32), jnp.full((1,), n, jnp.int32),
-            None)
-        row = np.asarray(logits[0, 0, :])
-        self.obs.device_span(td)
+        logits, updated = self._call(
+            self._paged_step, view,
+            (token, bt, np.full((1,), start, np.int32),
+             np.full((1,), n, np.int32), None),
+            width=bucket, prefill_tokens=n, decode_tokens=0)
+        row = logits[0, 0, :]
         self.obs.step_family(f"prefill[1,{bucket}]", n, bucket)
         if self.nan_guard and not np.isfinite(row).all():
             self.scheduler.cancel(req.rid, "error", cache_pages=False)

@@ -195,8 +195,8 @@ class FaultPlan:
 
         orig_paged = engine._run_paged
 
-        def run_paged(token, bt, lens, counts, idx):
-            rows = orig_paged(token, bt, lens, counts, idx)
+        def run_paged(token, bt, lens, counts, idx, **counts_kw):
+            rows = orig_paged(token, bt, lens, counts, idx, **counts_kw)
             if "nan" in plan._armed:
                 slot = plan._victim_slot(engine)
                 if slot is not None and plan._take("nan"):
@@ -208,8 +208,8 @@ class FaultPlan:
         if getattr(engine, "_flat_step", None) is not None:
             orig_flat = engine._run_flat
 
-            def run_flat(token, bt, row_ids, q_pos, idx):
-                out = orig_flat(token, bt, row_ids, q_pos, idx)
+            def run_flat(token, bt, row_ids, q_pos, idx, **counts_kw):
+                out = orig_flat(token, bt, row_ids, q_pos, idx, **counts_kw)
                 if "nan" in plan._armed:
                     slot = plan._victim_slot(engine)
                     if slot is not None and plan._take("nan"):

@@ -1,8 +1,8 @@
-"""Performance attribution layer (repro.obs.attrib / monitors / export).
+"""Performance attribution layer (repro.obs.attrib / monitors).
 
 Contracts covered:
   - attribution completeness: per step record the four wall components
-    (``sched + device + draft + host``) sum back to the measured wall
+    (``sched + call + draft + host``) sum back to the measured wall
     within float tolerance — chunked and flat, speculation on and off —
     and the drain totals inherit the identity;
   - warmup-only cost model: ``Engine.warmup()`` with telemetry on builds
@@ -10,10 +10,6 @@ Contracts covered:
     compiled ladder, attribution stays observer-grade (token identity vs
     a telemetry-off drain, zero post-warmup XLA traces), and without
     telemetry no model is built;
-  - the Prometheus text exposition passes the pure-python lint and its
-    counters are monotone across consecutive scrapes;
-  - the single-file HTML report carries the waterfall, the per-family
-    table and the alert log; ``write_report`` drops the ``.prom`` twin;
   - anomaly monitors: a vanishing ITL SLO target forces a ``slo-burn``
     alert exactly once per excursion, and the alert rides the telemetry
     dict + the counter;
@@ -29,8 +25,6 @@ from repro.configs import RunConfig, ShapeSpec, get_config, reduced_config
 from repro.models.model import build_model
 from repro.obs.attrib import (StepCostModel, fresh_totals, summarize,
                               update_aggregates)
-from repro.obs.export import html_report, lint_prometheus, prometheus_text, \
-    write_report
 from repro.obs.monitors import Monitors
 from repro.serving.engine import Engine
 
@@ -88,13 +82,14 @@ def test_attribution_components_sum_to_wall(smollm, kw):
     recs = list(eng.obs.step_records)
     assert recs, "drain produced no attribution records"
     for rec in recs:
-        parts = rec["sched"] + rec["device"] + rec["draft"] + rec["host"]
+        parts = rec["sched"] + rec["call"] + rec["draft"] + rec["host"]
         assert abs(parts - rec["wall"]) <= 1e-9 + 1e-6 * rec["wall"], rec
         assert rec["sched"] >= 0 and rec["host"] >= 0
         assert rec["families"], "every moving step is family-tagged"
+        assert rec["call"] > 0, "every moving step calls the model"
     # the drain totals inherit the identity
     tot = eng.obs.attribution_summary()["totals"]
-    comp = tot["sched_s"] + tot["device_s"] + tot["draft_s"] + tot["host_s"]
+    comp = tot["sched_s"] + tot["call_s"] + tot["draft_s"] + tot["host_s"]
     assert comp == pytest.approx(tot["wall_s"], rel=1e-6)
     assert tot["steps"] == len(recs)
     if "spec_tokens" in kw:
@@ -129,7 +124,7 @@ def test_update_aggregates_survives_window_eviction():
     """The running aggregates are independent of the bounded record
     window: folding records one at a time (then discarding them) yields
     the same totals as keeping all of them."""
-    recs = [{"wall": 0.5 + i * 0.01, "sched": 0.1, "device": 0.3,
+    recs = [{"wall": 0.5 + i * 0.01, "sched": 0.1, "call": 0.3,
              "draft": 0.0, "host": 0.1 + i * 0.01,
              "families": (("decode[3,1]", 2 + (i % 2), 3, 0.3),)}
             for i in range(10)]
@@ -196,88 +191,6 @@ def test_attribution_is_an_observer(smollm):
 
 
 # ---------------------------------------------------------------------------
-# exposition formats
-# ---------------------------------------------------------------------------
-
-def test_prometheus_text_lints_clean_and_counters_monotone(smollm):
-    cfg, m, params = smollm
-    eng = Engine(m, params, max_slots=3, page_tokens=8, chunk_tokens=16,
-                 token_budget=24, telemetry=True)
-    eng.warmup()
-    reqs = list(zip(_prompts(cfg, REQS[0]), REQS[1]))
-    _drain(eng, reqs)
-
-    def scrape():
-        text = prometheus_text(eng.obs)
-        assert lint_prometheus(text) == [], lint_prometheus(text)
-        vals = {}
-        for line in text.splitlines():
-            if line.startswith("#") or not line.strip():
-                continue
-            name, val = line.rsplit(" ", 1)
-            vals[name] = float(val)
-        return text, vals
-
-    text, first = scrape()
-    assert "repro_tokens_out_total" in first
-    assert first["repro_tokens_out_total"] == sum(n for _, n in reqs)
-    assert any(k.startswith("repro_family_steps_total{family=")
-               for k in first)
-    assert 0 < first["repro_mfu"] < 1
-    assert first["repro_goodput_ratio"] == 1.0
-
-    _drain(eng, reqs)                          # second drain, no reset
-    _, second = scrape()
-    for name, v in first.items():
-        if name.endswith("_total}") or "_total{" in name \
-                or name.endswith("_total"):
-            assert second.get(name, 0.0) >= v, f"counter {name} regressed"
-
-
-def test_prometheus_lint_catches_format_violations():
-    """The lint is a real gate, not a rubber stamp."""
-    assert lint_prometheus("# TYPE x counter\nx_total 1\n") == []
-    bad = [
-        "# TYPE x counter\nx 1\n",                     # counter sans _total
-        "# TYPE x counter\nx_total -1\n",              # negative counter
-        "x_total 1\n",                                 # sample without TYPE
-        "# TYPE x gauge\nx 1\nx 2\n",                  # duplicate sample
-        '# TYPE x gauge\nx{__bad="y"} 1\n',            # reserved label
-        "# TYPE x gauge\nx notafloat\n",               # unparseable value
-    ]
-    for text in bad:
-        assert lint_prometheus(text), f"lint missed: {text!r}"
-
-
-def test_html_report_schema_and_write(smollm, tmp_path):
-    cfg, m, params = smollm
-    eng = Engine(m, params, max_slots=3, page_tokens=8, chunk_tokens=16,
-                 token_budget=24, telemetry=True)
-    eng.warmup()
-    _drain(eng, list(zip(_prompts(cfg, REQS[0]), REQS[1])))
-
-    page = html_report(eng.obs, title="t&t")
-    assert page.startswith("<!doctype html>")
-    assert "t&amp;t" in page                   # titles are escaped
-    for marker in ("Attribution waterfall", "Per-family predicted vs",
-                   "Latency percentiles", "Alerts", "class='bar'",
-                   "cost model:"):
-        assert marker in page, f"report missing {marker!r}"
-    assert "<script" not in page               # self-contained, no JS
-    for label in eng.obs.attribution_summary()["families"]:
-        assert label.replace("<", "&lt;") in page
-
-    # the telemetry(report=...) path writes the .html/.prom pair
-    tel = eng.telemetry(report=tmp_path / "drain")
-    paths = tel["report"]
-    html_text = open(paths["html"]).read()
-    prom_text = open(paths["prom"]).read()
-    assert html_text == html_report(eng.obs)
-    assert lint_prometheus(prom_text) == []
-    assert tel["attribution"]["totals"]["steps"] > 0
-
-
-# ---------------------------------------------------------------------------
 # anomaly monitors
 # ---------------------------------------------------------------------------
 
@@ -326,7 +239,7 @@ def test_monitor_rules_standalone():
         sched.waiting = list(range(3 * (i + 1)))   # monotone growth >= 2
         fired += mon.observe_step(t=float(i), scheduler=sched,
                                   telemetry=tel, families=[],
-                                  device_s=0.0)
+                                  call_s=0.0)
     kinds = [a.kind for a in fired]
     assert kinds.count("preempt-storm") == 1   # re-armed only on clearing
     assert kinds.count("queue-growth") == 1
@@ -334,10 +247,10 @@ def test_monitor_rules_standalone():
     sched.waiting = []
     for i in range(6):
         fired += mon.observe_step(t=10.0 + i, scheduler=sched,
-                                  telemetry=tel, families=[], device_s=0.0)
+                                  telemetry=tel, families=[], call_s=0.0)
     sched.num_preemptions += 20
     fired += mon.observe_step(t=20.0, scheduler=sched, telemetry=tel,
-                              families=[], device_s=0.0)
+                              families=[], call_s=0.0)
     assert [a.kind for a in fired].count("preempt-storm") == 2
 
 
@@ -361,16 +274,16 @@ def test_step_outlier_detects_spike_per_family():
     for i in range(10):                        # warm the rolling median
         mon.observe_step(t=float(i), scheduler=sched, telemetry=tel,
                          families=[("flat[1,16]/k1", 8, 16, 0.010)],
-                         device_s=0.010)
+                         call_s=0.010)
     fired = mon.observe_step(t=11.0, scheduler=sched, telemetry=tel,
                              families=[("flat[1,16]/k1", 8, 16, 0.100)],
-                             device_s=0.100)
+                             call_s=0.100)
     assert [a.kind for a in fired] == ["step-outlier"]
     assert "flat[1,16]/k1" in fired[0].message
     # a different family with no warm window never alerts
     fired = mon.observe_step(t=12.0, scheduler=sched, telemetry=tel,
                              families=[("chunk[3,16]", 8, 48, 0.500)],
-                             device_s=0.500)
+                             call_s=0.500)
     assert fired == []
 
 
@@ -458,8 +371,7 @@ def test_obs_attrib_modules_pass_ast_lint():
     repo = Path(__file__).resolve().parent.parent
     obs = repo / "src" / "repro" / "obs"
     serving = repo / "src" / "repro" / "serving"
-    targets = [obs / "attrib.py", obs / "monitors.py", obs / "export.py",
-               obs / "telemetry.py"]
+    targets = [obs / "attrib.py", obs / "monitors.py", obs / "telemetry.py"]
     assert all(p.exists() for p in targets)
     findings = lint_paths(targets, serving_root=serving,
                           clock_roots=(serving, obs))

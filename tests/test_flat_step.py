@@ -243,13 +243,13 @@ def test_flat_budget_exactness(smollm):
     seen = []
     orig = eng._run_flat
 
-    def spy(token, bt, row_ids, q_pos, idx):
+    def spy(token, bt, row_ids, q_pos, idx, **counts):
         decoding = {s for s, r in eng.scheduler.running.items()
                     if r.status == "running"}
         real = row_ids[row_ids >= 0]
         seen.append((int(real.size), set(int(x) for x in np.unique(real)),
                      decoding, row_ids.size))
-        return orig(token, bt, row_ids, q_pos, idx)
+        return orig(token, bt, row_ids, q_pos, idx, **counts)
 
     eng._run_flat = spy
     reqs = list(zip(_prompts(cfg, [13, 21, 3, 16]), [8, 6, 10, 7]))

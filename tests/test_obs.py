@@ -19,7 +19,11 @@ Contracts covered:
     ``fault:nan`` / ``quarantine`` / ``shed`` events in the trace and
     the matching counters in the registry;
   - ``Engine.telemetry()`` exposes TTFT/ITL percentiles and honours the
-    explicit per-drain reset.
+    explicit per-drain reset;
+  - under ``jax.profiler`` each flat step is one ``engine.step`` span
+    holding its six phases in order, ``engine.dispatch`` carries the
+    step's planned width and token counts, and the traced drain is
+    token-identical to an untraced one with zero post-warmup retraces.
 """
 
 import json
@@ -129,10 +133,11 @@ def test_registry_reset_scopes():
 
 def test_null_telemetry_is_inert():
     """The default recorder never touches a clock or allocates — every
-    event hook is a no-op and ``clock()`` is a constant."""
+    event hook is a no-op and a span is a bare profiler annotation."""
+    from jax.profiler import TraceAnnotation
     assert not NULL.enabled
     assert NULL.registry is None and NULL.tracer is None
-    assert NULL.clock() == 0.0
+    assert type(NULL.span("engine.step", step=1)) is TraceAnnotation
     NULL.step_begin()
     NULL.step_end(None, None, [])              # no attribute access at all
     assert isinstance(Telemetry(), NullTelemetry)   # engines accept both
@@ -191,6 +196,93 @@ def test_telemetry_zero_retrace_after_warmup(smollm):
     assert dict(m.trace_counts) == before, \
         f"telemetry retraced: {before} -> {dict(m.trace_counts)}"
     assert eng.obs.registry.snapshot()["steps"] > 0
+
+
+# ---------------------------------------------------------------------------
+# step phases in the profiler's trace
+# ---------------------------------------------------------------------------
+
+PHASES = ("engine.schedule", "engine.pack", "engine.dispatch",
+          "engine.device_wait", "engine.logits_to_host", "engine.commit")
+
+
+@pytest.fixture(scope="module")
+def profiled(smollm, tmp_path_factory):
+    """A warmed flat engine drained under ``jax.profiler``, with the plan
+    of every step (its segments and ladder width) recorded on the side;
+    returns (engine, finished, plans, trace spans, retraces)."""
+    import glob
+
+    from jax.profiler import ProfileData
+    cfg, m, params = smollm
+    eng = Engine(m, params, max_slots=3, page_tokens=8, chunk_tokens=16,
+                 token_budget=24)
+    eng.warmup()
+    plans = []
+    plan_segments = eng.scheduler.plan_segments
+
+    def spy(decode_counts, budget):
+        segs = plan_segments(decode_counts, budget)
+        total = sum(n for _, _, n in segs)
+        plans.append({"width": eng._flat_shape(total),
+                      "prefill_tokens": sum(n for _, k, n in segs
+                                            if k == "prefill"),
+                      "decode_tokens": sum(n for _, k, n in segs
+                                           if k == "decode")})
+        return segs
+    eng.scheduler.plan_segments = spy
+    before = dict(m.trace_counts)
+    out = str(tmp_path_factory.mktemp("profile"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(out, profiler_options=opts)
+    try:
+        fin = _drain(eng, list(zip(_prompts(cfg, REQS[0]), REQS[1])))
+    finally:
+        jax.profiler.stop_trace()
+    retraces = {k: v - before[k] for k, v in m.trace_counts.items()}
+    path = glob.glob(f"{out}/**/*.xplane.pb", recursive=True)[0]
+    spans = [(ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("engine.")]
+    return eng, fin, plans, sorted(spans, key=lambda e: e[1]), retraces
+
+
+def test_flat_step_phases_nest_in_order(profiled):
+    """Per step: one ``engine.step`` whose six phases appear in order,
+    do not overlap and lie inside it."""
+    eng, _, _, spans, _ = profiled
+    steps = [e for e in spans if e[0] == "engine.step"]
+    assert len(steps) == eng._steps > 0
+    for name, lo, hi, _ in steps:
+        inner = [e for e in spans if lo <= e[1] < hi and e[0] in PHASES]
+        assert tuple(e[0] for e in inner) == PHASES
+        assert all(a[2] <= b[1] for a, b in zip(inner, inner[1:]))
+        assert lo <= inner[0][1] and inner[-1][2] <= hi
+
+
+def test_dispatch_args_are_the_planned_step(profiled):
+    eng, _, plans, spans, _ = profiled
+    got = [e[3] for e in spans if e[0] == "engine.dispatch"]
+    assert [a.pop("step") for a in got] == list(range(eng._steps))
+    assert got == plans
+    assert any(p["prefill_tokens"] == 0 for p in plans)
+    assert any(p["prefill_tokens"] and p["decode_tokens"] for p in plans)
+    copies = [e[3]["bytes"] for e in spans if e[0] == "engine.logits_to_host"]
+    assert copies and all(b > 0 for b in copies)
+
+
+def test_profiled_drain_is_token_identical_without_retraces(smollm,
+                                                            profiled):
+    cfg, m, params = smollm
+    _, fin, _, _, retraces = profiled
+    plain = Engine(m, params, max_slots=3, page_tokens=8, chunk_tokens=16,
+                   token_budget=24)
+    want = [r.out_tokens for r in
+            _drain(plain, list(zip(_prompts(cfg, REQS[0]), REQS[1])))]
+    assert [r.out_tokens for r in fin] == want
+    assert not any(retraces.values()), retraces
 
 
 # ---------------------------------------------------------------------------
@@ -258,13 +350,13 @@ def test_trace_export_covers_lifecycle_under_pressure(smollm, tmp_path):
 
     names = {e["name"] for e in evs}
     tracks = {e["args"]["name"] for e in evs if e["ph"] == "M"}
-    assert {"engine", "scheduler", "pool"} <= tracks
+    assert {"scheduler", "pool"} <= tracks
     assert any(t.startswith("slot ") for t in tracks)
     # lifecycle spans: every request waits in queue (async), prefills and
     # decodes (X spans on its slot track)
     queues = [e for e in evs if e["ph"] == "b" and e["name"] == "queue"]
     assert {e["id"] for e in queues} >= {r.rid for r in fin}
-    for span in ("prefill", "decode", "step", "device"):
+    for span in ("prefill", "decode"):
         assert span in names, f"missing {span} spans"
     instants = {e["name"] for e in evs if e["ph"] == "i"}
     assert "preempt" in instants or "pause" in instants
@@ -328,8 +420,8 @@ def test_engine_telemetry_percentiles_and_reset(smollm):
     assert met["requests_finished"] == len(reqs)
     assert met["tokens_out"] == sum(n for _, n in reqs)
     assert met["step_wall_s"]["count"] == met["steps"] > 0
-    # device time is a subset of wall time, measured per step
-    assert met["step_device_s"]["count"] == met["steps"]
+    # call time is a subset of wall time, measured per step
+    assert met["step_call_s"]["count"] == met["steps"]
 
     # the reset zeroed the drain scope; a second drain starts clean
     after = eng.telemetry()
