@@ -46,6 +46,7 @@ from repro.configs.base import ShapeSpec  # noqa: E402
 from repro.kernels.ragged_attn import (ragged_attention,  # noqa: E402
                                        ragged_attention_reference)
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+from repro.models.attention import KVPageLayout  # noqa: E402
 from repro.models.model import build_model  # noqa: E402
 from repro.serving.engine import Engine  # noqa: E402
 from repro.serving.kv_cache import fresh_slot_states, prefill_view  # noqa: E402
@@ -70,19 +71,20 @@ CROSS_TOL = 0.05
 def _segment_mix(hq, hkv, dh, dtype, seed):
     """Kernel inputs: a decode row at position 17, a 5-token chunk at 8..12,
     a fresh 4-token prefill and padding, over a shuffled 16-token-page
-    pool."""
-    t, pages, mp, w = PAGE_TOKENS, 9, 3, 16
+    pool of two layers, read at the second."""
+    t, layers, pages, mp, w = PAGE_TOKENS, 2, 9, 3, 16
+    shape = (layers, pages) + KVPageLayout(hkv, dh, t).page_shape
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     q = jax.random.normal(ks[0], (w, hq, dh)).astype(dtype)
-    k_pages = jax.random.normal(ks[1], (pages, t, hkv, dh)).astype(dtype)
-    v_pages = jax.random.normal(ks[2], (pages, t, hkv, dh)).astype(dtype)
+    k_pages = jax.random.normal(ks[1], shape).astype(dtype)
+    v_pages = jax.random.normal(ks[2], shape).astype(dtype)
     bt = jax.random.permutation(ks[3], pages)[: 3 * mp].reshape(3, mp)
     row_ids = np.full(w, -1, np.int32)
     q_pos = np.zeros(w, np.int32)
     row_ids[0], q_pos[0] = 0, 17
     row_ids[1:6], q_pos[1:6] = 1, np.arange(8, 13)
     row_ids[6:10], q_pos[6:10] = 2, np.arange(4)
-    args = dict(block_tables=bt.astype(jnp.int32),
+    args = dict(layer=jnp.int32(layers - 1), block_tables=bt.astype(jnp.int32),
                 row_ids=jnp.asarray(row_ids), q_pos=jnp.asarray(q_pos))
     return q, k_pages, v_pages, args, row_ids >= 0
 

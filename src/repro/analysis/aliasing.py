@@ -98,31 +98,35 @@ def lint_kv_writes(walker: TaintWalker, family: str,
 def _attention_unit_walkers(engine):
     """Taint-walk the unit KV-update functions at this engine's shapes."""
     from repro.models import attention
+    from repro.models.transformer import pattern_period
     model = engine.model
     cfg = model.cfg
     S = jax.ShapeDtypeStruct
     i32, dt = jnp.int32, model.compute_dtype
     pool = engine.pool
-    t = pool.page_tokens
-    cache = {"k_pages": S((pool.num_pages, t, cfg.n_kv_heads, cfg.d_head), dt),
-             "v_pages": S((pool.num_pages, t, cfg.n_kv_heads, cfg.d_head), dt)}
+    lay = attention.KVPageLayout(cfg.n_kv_heads, cfg.d_head, pool.page_tokens)
+    groups = cfg.n_layers // pattern_period(cfg)
+    shp = (groups, pool.num_pages) + lay.page_shape
+    cache = {"k_pages": S(shp, dt), "v_pages": S(shp, dt)}
     b, mp = engine.slots, engine.max_pages
     out = []
     s = engine.chunk_tokens or engine._bucket
     kv = S((b, s, cfg.n_kv_heads, cfg.d_head), dt)
     out.append(("paged_kv_update", taint_step(
-        lambda c, k, v, bt, ln, nc: attention.paged_kv_update(
-            c, k, v, block_tables=bt, lens=ln, new_counts=nc),
-        (cache, kv, kv, S((b, mp), i32), S((b,), i32), S((b,), i32)),
+        lambda c, k, v, bt, ln, nc, g: attention.paged_kv_update(
+            c, k, v, layer=g, block_tables=bt, lens=ln, new_counts=nc),
+        (cache, kv, kv, S((b, mp), i32), S((b,), i32), S((b,), i32),
+         S((), i32)),
         {0: lambda p: "pages" if "_pages" in p else None,
          3: "block_tables", 4: "validity", 5: "validity"}), 2))
     if engine.flat:
         w = engine._flat_shapes()[0]
         kvf = S((1, w, cfg.n_kv_heads, cfg.d_head), dt)
         out.append(("flat_paged_kv_update", taint_step(
-            lambda c, k, v, bt, r, q: attention.flat_paged_kv_update(
-                c, k, v, block_tables=bt, row_ids=r, q_pos=q),
-            (cache, kvf, kvf, S((b, mp), i32), S((w,), i32), S((w,), i32)),
+            lambda c, k, v, bt, r, q, g: attention.flat_paged_kv_update(
+                c, k, v, layer=g, block_tables=bt, row_ids=r, q_pos=q),
+            (cache, kvf, kvf, S((b, mp), i32), S((w,), i32), S((w,), i32),
+             S((), i32)),
             {0: lambda p: "pages" if "_pages" in p else None,
              3: "block_tables", 4: "validity", 5: "validity"}), 2))
     return out

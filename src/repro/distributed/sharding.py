@@ -165,14 +165,15 @@ def cache_specs(caches, mesh, run: RunConfig, global_batch: int) -> dict:
     def spec(path, leaf):
         nd = leaf.ndim
         if path.endswith(("/k_pages", "/v_pages")):
-            # paged pool [G, P, T, Hkv, dh]: the page dim is the (chunked)
-            # sequence dim — shard it over "model" (distributed flash-decode
-            # over page shards); tiles [T, dh] are never split, the
+            # paged pool [G, P, Hkv, T/f, f*dh] (models/attention.py
+            # KVPageLayout): the page dim is the (chunked) sequence dim —
+            # shard it over "model" (distributed flash-decode over page
+            # shards); a head's page tile [T/f, f*dh] is never split, the
             # distributed extension of the layout contract.
             lead = (None,) * (nd - 4)
             if run.seq_shard_kv:
                 return P(*lead, "model", None, None, None)
-            return P(*lead, None, None, "model", None)
+            return P(*lead, None, "model", None, None)
         if path.endswith("/k") or path.endswith("/v"):
             # [G, B, S, Hkv, dh] (stacked) or [B, S, Hkv, dh]
             lead = (None,) * (nd - 4)

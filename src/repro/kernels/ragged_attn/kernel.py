@@ -1,12 +1,20 @@
 """Pallas TPU kernel for segment-masked ragged paged attention.
 
 One grid step per (flat query position, page): program ``(i, p)`` loads
-query ``i``'s row page ``p`` straight from the pool via scalar-prefetched
-``block_tables[row_ids[i], p]`` (PrefetchScalarGridSpec — the page id is
-known before the body runs, so the K/V block DMA is index-driven, the
-paged-attention pattern), applies the segment causal mask
-``p*T + t <= q_pos[i]``, and folds the page into an online-softmax
-accumulator.  The last page normalises and writes the output row.
+query ``i``'s row page ``p`` of layer ``layer`` straight from the stacked
+pool via scalar-prefetched ``(layer, block_tables[row_ids[i], p])``
+(PrefetchScalarGridSpec — the page id is known before the body runs, so
+the K/V block DMA is index-driven, the paged-attention pattern), applies
+the segment causal mask ``kv_pos <= q_pos[i]``, and folds the page into an
+online-softmax accumulator.  The last page normalises and writes the
+output row.
+
+Pages arrive as :class:`~repro.models.attention.KVPageLayout` lays them
+out, ``[Hkv, T/f, f*dh]``: row ``r``'s lane slice ``j`` holds token
+``r*f + j``, so the body scores each of the ``f`` lane slices against the
+query (kv position ``p*T + r*f + j``) and accumulates ``PV`` per slice.
+Reading the pool where the layer scan keeps it, in the layout the chip
+stores it in, is what lets the step update the pool in place.
 
 The caller splits the head axis into ``[Hkv, g]`` before the call, so
 each query block is ``(1, Hkv, g, dh)`` with its last two dims whole.
@@ -30,11 +38,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.models.attention import KVPageLayout
+
 __all__ = ["ragged_attention_kernel_call"]
 
 
-def _kernel(row_ids_ref, q_pos_ref, bt_ref, q_ref, k_ref, v_ref, out_ref,
-            m_ref, l_ref, acc_ref, *, t: int, dh: int):
+def _kernel(row_ids_ref, q_pos_ref, bt_ref, layer_ref, q_ref, k_ref, v_ref,
+            out_ref, m_ref, l_ref, acc_ref, *, t: int, f: int, dh: int):
+    del layer_ref
     i = pl.program_id(0)
     p = pl.program_id(1)
     np_ = pl.num_programs(1)
@@ -55,23 +66,35 @@ def _kernel(row_ids_ref, q_pos_ref, bt_ref, q_ref, k_ref, v_ref, out_ref,
     @pl.when(p * t <= qp)  # pages fully past the query hold nothing visible
     def _fold():
         q = q_ref[0].astype(jnp.float32)                    # [Hkv, g, dh]
-        k = k_ref[0].astype(jnp.float32)                    # [T, Hkv, dh]
-        v = v_ref[0].astype(jnp.float32)
-        s = jnp.einsum("hgd,thd->hgt", q, k, precision=prec,
-                       preferred_element_type=jnp.float32) * dh ** -0.5
-        kv_pos = p * t + jax.lax.broadcasted_iota(jnp.int32, (1, 1, t), 2)
-        s = jnp.where(kv_pos <= qp, s, -jnp.inf)            # [Hkv, g, T]
+        k = k_ref[...].astype(jnp.float32)                  # [Hkv, T/f, f*dh]
+        v = v_ref[...].astype(jnp.float32)
+        row = jax.lax.broadcasted_iota(jnp.int32, (1, 1, t // f), 2)
+        s, live = [], []
+        for j in range(f):                                  # token r*f + j
+            s_j = jnp.einsum("hgd,hrd->hgr", q, k[:, :, j * dh:(j + 1) * dh],
+                             precision=prec,
+                             preferred_element_type=jnp.float32) * dh ** -0.5
+            live.append(p * t + row * f + j <= qp)
+            s.append(jnp.where(live[j], s_j, -jnp.inf))     # [Hkv, g, T/f]
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        m_new = m_prev
+        for s_j in s:
+            m_new = jnp.maximum(m_new, jnp.max(s_j, axis=-1))
         alpha = jnp.where(jnp.isfinite(m_new),
                           jnp.exp(m_prev - m_new), jnp.zeros_like(m_new))
-        e = jnp.exp(s - m_new[..., None])
-        e = jnp.where(kv_pos <= qp, e, jnp.zeros_like(e))
+        l_new = l_ref[...] * alpha
+        acc = acc_ref[...] * alpha[..., None]
+        for j, s_j in enumerate(s):
+            e = jnp.exp(s_j - m_new[..., None])
+            e = jnp.where(live[j], e, jnp.zeros_like(e))
+            l_new = l_new + jnp.sum(e, axis=-1)
+            acc = acc + jnp.einsum("hgr,hrd->hgd", e,
+                                   v[:, :, j * dh:(j + 1) * dh],
+                                   precision=prec,
+                                   preferred_element_type=jnp.float32)
         m_ref[...] = m_new
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(e, axis=-1)
-        acc_ref[...] = (acc_ref[...] * alpha[..., None]
-                        + jnp.einsum("hgt,thd->hgd", e, v, precision=prec,
-                                     preferred_element_type=jnp.float32))
+        l_ref[...] = l_new
+        acc_ref[...] = acc
 
     @pl.when(p == np_ - 1)
     def _emit():
@@ -81,30 +104,34 @@ def _kernel(row_ids_ref, q_pos_ref, bt_ref, q_ref, k_ref, v_ref, out_ref,
 
 
 def ragged_attention_kernel_call(q: jnp.ndarray, k_pages: jnp.ndarray,
-                                 v_pages: jnp.ndarray, *,
+                                 v_pages: jnp.ndarray, *, layer: jnp.ndarray,
                                  block_tables: jnp.ndarray,
                                  row_ids: jnp.ndarray, q_pos: jnp.ndarray,
                                  interpret: bool = False) -> jnp.ndarray:
-    """q: [W, Hq, dh]; pages: [P, T, Hkv, dh]; block_tables: [B, MP];
-    row_ids/q_pos: [W].  Returns [W, Hq, dh]."""
+    """q: [W, Hq, dh]; pages: [G, P, *page_shape] (``KVPageLayout``), read
+    at layer ``layer``; block_tables: [B, MP]; row_ids/q_pos: [W].
+    Returns [W, Hq, dh]."""
     w, hq, dh = q.shape
-    t, hkv = k_pages.shape[1], k_pages.shape[2]
+    lay = KVPageLayout.of_pool(k_pages, dh)
+    hkv = lay.n_kv_heads
     g = hq // hkv
     mp = block_tables.shape[1]
     row_ids = jnp.maximum(row_ids.astype(jnp.int32), 0)
     q_pos = q_pos.astype(jnp.int32)
+    layer = jnp.reshape(layer, (1,)).astype(jnp.int32)
 
-    def page_map(i, p, row_ids_ref, q_pos_ref, bt_ref):
+    def page_map(i, p, row_ids_ref, q_pos_ref, bt_ref, layer_ref):
         del q_pos_ref
-        return (bt_ref[row_ids_ref[i], p], 0, 0, 0)
+        return (layer_ref[0], bt_ref[row_ids_ref[i], p], 0, 0, 0)
 
+    page_block = pl.BlockSpec((None, None) + lay.page_shape, page_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(w, mp),
         in_specs=[
             pl.BlockSpec((1, hkv, g, dh), lambda i, p, *_: (i, 0, 0, 0)),
-            pl.BlockSpec((1, t, hkv, dh), page_map),
-            pl.BlockSpec((1, t, hkv, dh), page_map),
+            page_block,
+            page_block,
         ],
         out_specs=pl.BlockSpec((1, hkv, g, dh), lambda i, p, *_: (i, 0, 0, 0)),
         scratch_shapes=[
@@ -113,13 +140,13 @@ def ragged_attention_kernel_call(q: jnp.ndarray, k_pages: jnp.ndarray,
             pltpu.VMEM((hkv, g, dh), jnp.float32),    # unnormalised context
         ],
     )
-    kernel = functools.partial(_kernel, t=t, dh=dh)
+    kernel = functools.partial(_kernel, t=lay.page_tokens, f=lay.fold, dh=dh)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((w, hkv, g, dh), q.dtype),
         interpret=interpret,
         name="ragged_attn",
-    )(row_ids, q_pos, block_tables, q.reshape(w, hkv, g, dh), k_pages,
-      v_pages)
+    )(row_ids, q_pos, block_tables, layer, q.reshape(w, hkv, g, dh),
+      k_pages, v_pages)
     return out.reshape(w, hq, dh)

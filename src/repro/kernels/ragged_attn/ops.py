@@ -22,13 +22,15 @@ __all__ = ["ragged_attention", "ragged_attention_reference"]
 
 
 def ragged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
-                     v_pages: jnp.ndarray, *, block_tables: jnp.ndarray,
+                     v_pages: jnp.ndarray, *, layer: jnp.ndarray,
+                     block_tables: jnp.ndarray,
                      row_ids: jnp.ndarray, q_pos: jnp.ndarray,
                      use_kernel: Optional[bool] = None,
                      interpret: bool = False) -> jnp.ndarray:
-    """q: [W, Hq, dh] flat queries; k_pages/v_pages: [P, T, Hkv, dh] pool;
-    block_tables: [B, MP]; row_ids: [W] (-1 = pad); q_pos: [W].
-    Returns [W, Hq, dh]."""
+    """q: [W, Hq, dh] flat queries; k_pages/v_pages: [G, P, *page_shape]
+    pools of every layer (models/attention.py ``KVPageLayout``), read at
+    layer ``layer`` (int32 scalar); block_tables: [B, MP]; row_ids: [W]
+    (-1 = pad); q_pos: [W].  Returns [W, Hq, dh]."""
     on_tpu = jax.default_backend() == "tpu"
     if use_kernel is None:
         use_kernel = on_tpu
@@ -38,15 +40,15 @@ def ragged_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     if use_kernel:
         from repro.kernels.ragged_attn.kernel import ragged_attention_kernel_call
         return ragged_attention_kernel_call(
-            q, k_pages, v_pages, block_tables=block_tables,
+            q, k_pages, v_pages, layer=layer, block_tables=block_tables,
             row_ids=row_ids, q_pos=q_pos, interpret=interpret)
-    return ragged_attention_ref(q, k_pages, v_pages,
+    return ragged_attention_ref(q, k_pages, v_pages, layer=layer,
                                 block_tables=block_tables,
                                 row_ids=row_ids, q_pos=q_pos)
 
 
-def ragged_attention_reference(q, k_pages, v_pages, *, block_tables,
+def ragged_attention_reference(q, k_pages, v_pages, *, layer, block_tables,
                                row_ids, q_pos):
-    return ragged_attention_ref(q, k_pages, v_pages,
+    return ragged_attention_ref(q, k_pages, v_pages, layer=layer,
                                 block_tables=block_tables,
                                 row_ids=row_ids, q_pos=q_pos)

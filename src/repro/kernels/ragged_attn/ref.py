@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models.attention import KVPageLayout
+
 __all__ = ["ragged_attention_ref", "flat_write_destinations"]
 
 
@@ -28,7 +30,9 @@ def flat_write_destinations(block_tables: np.ndarray, row_ids: np.ndarray,
     """Host-side mirror of the flat scatter's addressing rule
     (:func:`repro.models.attention.flat_paged_kv_update`): position ``i``
     of the stream writes page ``block_tables[row_ids[i], q_pos[i] // T]``
-    at offset ``q_pos[i] % T``; ``row_ids[i] < 0`` routes to trash page 0.
+    at token offset ``q_pos[i] % T`` (of the step's layer; ``KVPageLayout``
+    places the offset in the page); ``row_ids[i] < 0`` routes to trash
+    page 0.
     Returns ``(pages, offsets, valid)``, each ``[W]``.
 
     This is the write-side half of the contract the oracle above reads
@@ -48,19 +52,21 @@ def flat_write_destinations(block_tables: np.ndarray, row_ids: np.ndarray,
 
 
 def ragged_attention_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
-                         v_pages: jnp.ndarray, *, block_tables: jnp.ndarray,
-                         row_ids: jnp.ndarray,
+                         v_pages: jnp.ndarray, *, layer: jnp.ndarray,
+                         block_tables: jnp.ndarray, row_ids: jnp.ndarray,
                          q_pos: jnp.ndarray) -> jnp.ndarray:
-    """q: [W, Hq, dh]; k_pages/v_pages: [P, T, Hkv, dh] pool (page 0 = trash);
-    block_tables: [B, MP]; row_ids: [W] int32 (-1 = padding — clamped to row
-    0, output garbage, caller discards); q_pos: [W] absolute positions.
-    Returns [W, Hq, dh] in q.dtype."""
+    """q: [W, Hq, dh]; k_pages/v_pages: [G, P, *page_shape] pools of every
+    layer (:class:`~repro.models.attention.KVPageLayout`, page 0 = trash),
+    read at layer ``layer``; block_tables: [B, MP]; row_ids: [W] int32
+    (-1 = padding — clamped to row 0, output garbage, caller discards);
+    q_pos: [W] absolute positions.  Returns [W, Hq, dh] in q.dtype."""
     w, hq, dh = q.shape
-    hkv = k_pages.shape[2]
+    lay = KVPageLayout.of_pool(k_pages, dh)
+    hkv = lay.n_kv_heads
     g = hq // hkv
     bt = block_tables[jnp.maximum(row_ids, 0)]                 # [W, MP]
-    k_all = k_pages[bt].reshape(w, -1, hkv, dh)                # [W, MP*T, ...]
-    v_all = v_pages[bt].reshape(w, -1, hkv, dh)
+    k_all = lay.tokens(k_pages[layer, bt]).reshape(w, -1, hkv, dh)
+    v_all = lay.tokens(v_pages[layer, bt]).reshape(w, -1, hkv, dh)
     qg = q.reshape(w, hkv, g, dh)
     scale = dh ** -0.5
     scores = jnp.einsum("qhgd,qkhd->qhgk", qg.astype(jnp.float32),

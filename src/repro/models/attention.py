@@ -12,7 +12,8 @@ decoder), KV-cache decode with a sequence-shardable cache.
 
 from __future__ import annotations
 
-from typing import Optional
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +25,8 @@ from repro.models.common import Stream, apply_rope, maybe_unpack, norm_apply, no
 Array = jnp.ndarray
 
 __all__ = ["attn_init", "attn_apply", "init_kv_cache", "init_paged_kv_cache",
-           "core_attention", "paged_kv_update", "flat_paged_kv_update"]
+           "KVPageLayout", "core_attention", "paged_kv_update",
+           "flat_paged_kv_update"]
 
 
 def attn_init(key, cfg: ModelConfig, dtype=jnp.float32, *, cross: bool = False) -> dict:
@@ -51,23 +53,107 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> dict:
     return {"k": jnp.zeros(shp, dtype), "v": jnp.zeros(shp, dtype)}
 
 
+class KVPageLayout(NamedTuple):
+    """Where each token's K (or V) lives inside one page of the paged pool.
+
+    A page is head-major and lane-dense: ``[Hkv, T/f, f*dh]`` with
+    ``f = gcd(128 // dh, T)`` consecutive tokens of one kv head side by side
+    in a row of at most 128 lanes, so token ``t`` sits at row ``t // f``,
+    lanes ``(t % f)*dh ..``.  At ``dh >= 128`` (``f = 1``) a page is simply
+    ``[Hkv, T, dh]``.  A minor dim of 128 lanes is one the chip keeps
+    row-major, which is what the ragged-attention kernel reads; a minor dim
+    of 64 (``[T, Hkv, 64]``) makes the compiler re-tile the pool around
+    every kernel call.  The pool is ``[G, P, *page_shape]``: page axis 1,
+    which copy-on-write and page sharding index.  The scatters, the kernel,
+    its oracle and the analyses all address pages through this class."""
+    n_kv_heads: int
+    d_head: int
+    page_tokens: int
+
+    @classmethod
+    def of_pool(cls, pages, d_head: int) -> "KVPageLayout":
+        """The layout of a pool (or page) array ``[..., Hkv, T/f, f*dh]``."""
+        hkv, rows, lanes = pages.shape[-3:]
+        return cls(hkv, d_head, rows * lanes // d_head)
+
+    @property
+    def fold(self) -> int:
+        """``f``: tokens of one kv head side by side in a page row."""
+        dh = self.d_head
+        if dh % 128 == 0:
+            return 1
+        if 128 % dh:
+            raise ValueError(f"d_head {dh} neither divides nor fills 128 "
+                             f"lanes")
+        return math.gcd(128 // dh, self.page_tokens)
+
+    @property
+    def page_shape(self) -> tuple:
+        f = self.fold
+        return (self.n_kv_heads, self.page_tokens // f, f * self.d_head)
+
+    def tokens(self, pages):
+        """``[..., Hkv, T/f, f*dh]`` pages -> ``[..., T, Hkv, dh]`` tokens."""
+        hkv, rows, _ = pages.shape[-3:]
+        lead = pages.shape[:-3]
+        x = pages.reshape(*lead, hkv, rows, self.fold, self.d_head)
+        x = jnp.moveaxis(x, -4, -2)                 # [..., rows, f, Hkv, dh]
+        return x.reshape(*lead, rows * self.fold, hkv, self.d_head)
+
+    def scatter(self, pool, layer, page, off, x):
+        """Write the token stream ``x`` [N, Hkv, dh] at ``pool[layer,
+        page[n], :, token off[n]]`` of a ``[G, P, *page_shape]`` pool, in
+        place (page 0, the trash page, may take any number of writes).
+
+        Each (token, head) writes its whole row of ``f*dh`` lanes: the
+        row's other tokens come from this stream where it holds them and
+        from the pool otherwise, so tokens sharing a row write the same
+        value.  A narrower window makes the chip's compiler scatter one
+        window at a time in a loop, and a window across heads (strided in
+        a head-major page) makes it re-tile the pool around the kernel."""
+        f, dh, hkv = self.fold, self.d_head, self.n_kv_heads
+        n = x.shape[0]
+        row = off // f
+        x = x.astype(pool.dtype)
+        if f > 1:
+            want = row[:, None] * f + jnp.arange(f)             # [N, f]
+            hit = ((page[None, None, :] == page[:, None, None])
+                   & (off[None, None, :] == want[:, :, None]))   # [N, f, N]
+            new = jnp.moveaxis(x[jnp.argmax(hit, axis=-1)], 1, 2)
+            heads = jnp.arange(hkv)[None, :]
+            old = pool[layer, page[:, None], heads, row[:, None]]
+            old = old.reshape(n, hkv, f, dh)
+            x = jnp.where(jnp.any(hit, axis=-1)[:, None, :, None], new, old)
+        at = lambda a: jnp.broadcast_to(a[:, None], (n, hkv))  # noqa: E731
+        idx = jnp.stack([jnp.full((n, hkv), layer), at(page),
+                         jnp.broadcast_to(jnp.arange(hkv), (n, hkv)),
+                         at(row)], axis=-1).astype(jnp.int32)   # [N, Hkv, 4]
+        dnums = jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(2,), inserted_window_dims=(0, 1, 2, 3),
+            scatter_dims_to_operand_dims=(0, 1, 2, 3))
+        return jax.lax.scatter(pool, idx, x.reshape(n, hkv, f * dh), dnums,
+                               mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
+
+
 def init_paged_kv_cache(cfg: ModelConfig, num_pages: int, page_tokens: int,
                         dtype) -> dict:
     """Paged pool: KV lives in ``num_pages`` pages of ``page_tokens`` tokens,
     shared by all sequences via per-request block tables (continuous
-    batching).  Page 0 is reserved as the trash page — writes for padded /
-    inactive positions are routed there so they can never corrupt a live
-    request."""
-    shp = (num_pages, page_tokens, cfg.n_kv_heads, cfg.d_head)
+    batching), each page laid out by :class:`KVPageLayout`.  Page 0 is
+    reserved as the trash page — writes for padded / inactive positions are
+    routed there so they can never corrupt a live request."""
+    lay = KVPageLayout(cfg.n_kv_heads, cfg.d_head, page_tokens)
+    shp = (num_pages,) + lay.page_shape
     return {"k_pages": jnp.zeros(shp, dtype), "v_pages": jnp.zeros(shp, dtype)}
 
 
-def paged_kv_update(cache: dict, k: Array, v: Array, *, block_tables: Array,
-                    lens: Array, new_counts: Array):
-    """Scatter this step's K/V into the page pool, gather each row's logical
-    KV stream back out.
+def paged_kv_update(cache: dict, k: Array, v: Array, *, layer: Array,
+                    block_tables: Array, lens: Array, new_counts: Array):
+    """Scatter this step's K/V into layer ``layer`` of the page pool, gather
+    each row's logical KV stream back out.
 
-    cache: {"k_pages","v_pages"} [P, T, Hkv, dh] — the pool (page 0 = trash).
+    cache: {"k_pages","v_pages"} [G, P, *page_shape] — every layer's pool
+    (page 0 = trash; :class:`KVPageLayout`), updated in place.
     k, v: [B, S, Hkv, dh] new keys/values; row b's token s sits at logical
     position ``lens[b] + s`` and is valid iff ``s < new_counts[b]`` (prefill
     rows are padded up to a layout-aligned bucket; invalid writes go to the
@@ -86,25 +172,27 @@ def paged_kv_update(cache: dict, k: Array, v: Array, *, block_tables: Array,
     bitwise those of a monolithic prefill at the same positions.
     """
     kp, vp = cache["k_pages"], cache["v_pages"]
-    t = kp.shape[1]
-    b, s = k.shape[0], k.shape[1]
+    b, s, hkv, dh = k.shape
+    lay = KVPageLayout.of_pool(kp, dh)
+    t = lay.page_tokens
     pos = lens[:, None] + jnp.arange(s, dtype=jnp.int32)        # [B,S]
     valid = jnp.arange(s)[None, :] < new_counts[:, None]
     slot = jnp.minimum(pos // t, block_tables.shape[1] - 1)
     page = jnp.take_along_axis(block_tables, slot, axis=1)
-    page = jnp.where(valid, page, 0)
-    off = jnp.where(valid, pos % t, 0)
-    kp = kp.at[page, off].set(k.astype(kp.dtype))
-    vp = vp.at[page, off].set(v.astype(vp.dtype))
-    k_all = kp[block_tables].reshape(b, -1, *kp.shape[2:])
-    v_all = vp[block_tables].reshape(b, -1, *vp.shape[2:])
+    page = jnp.where(valid, page, 0).reshape(-1)
+    off = jnp.where(valid, pos % t, 0).reshape(-1)
+    kp = lay.scatter(kp, layer, page, off, k.reshape(b * s, hkv, dh))
+    vp = lay.scatter(vp, layer, page, off, v.reshape(b * s, hkv, dh))
+    k_all = lay.tokens(kp[layer, block_tables]).reshape(b, -1, hkv, dh)
+    v_all = lay.tokens(vp[layer, block_tables]).reshape(b, -1, hkv, dh)
     mask = jnp.arange(k_all.shape[1])[None, :] < (lens + new_counts)[:, None]
     return {"k_pages": kp, "v_pages": vp}, k_all, v_all, mask
 
 
-def flat_paged_kv_update(cache: dict, k: Array, v: Array, *,
+def flat_paged_kv_update(cache: dict, k: Array, v: Array, *, layer: Array,
                          block_tables: Array, row_ids: Array, q_pos: Array):
-    """Scatter one flat ``[1, W]`` token stream's K/V into the page pool.
+    """Scatter one flat ``[1, W]`` token stream's K/V into layer ``layer``
+    of the page pool.
 
     Flat-segment layout contract (the token-level analogue of
     :func:`paged_kv_update`'s row contract): position ``i`` of the stream
@@ -115,19 +203,21 @@ def flat_paged_kv_update(cache: dict, k: Array, v: Array, *,
     decode segments (1+k positions), chunked-prefill segments, and padding
     up to the ``m_r``-aligned width W.
 
-    cache: {"k_pages","v_pages"} [P, T, Hkv, dh]; k, v: [1, W, Hkv, dh];
-    block_tables: [B, MP]; row_ids, q_pos: [W].  Returns the new cache —
-    the gather side lives in the ragged-attention op, which reads each
-    query's own page stream (kernels/ragged_attn)."""
+    cache: {"k_pages","v_pages"} [G, P, *page_shape], updated in place;
+    k, v: [1, W, Hkv, dh]; block_tables: [B, MP]; row_ids, q_pos: [W].
+    Returns the new cache — the gather side lives in the ragged-attention
+    op, which reads each query's own page stream of layer ``layer``
+    (kernels/ragged_attn)."""
     kp, vp = cache["k_pages"], cache["v_pages"]
-    t = kp.shape[1]
+    lay = KVPageLayout.of_pool(kp, k.shape[-1])
+    t = lay.page_tokens
     valid = row_ids >= 0
     row = jnp.maximum(row_ids, 0)
     slot = jnp.minimum(q_pos // t, block_tables.shape[1] - 1)
     page = jnp.where(valid, block_tables[row, slot], 0)
     off = jnp.where(valid, q_pos % t, 0)
-    kp = kp.at[page, off].set(k[0].astype(kp.dtype))
-    vp = vp.at[page, off].set(v[0].astype(vp.dtype))
+    kp = lay.scatter(kp, layer, page, off, k[0])
+    vp = lay.scatter(vp, layer, page, off, v[0])
     return {"k_pages": kp, "v_pages": vp}
 
 
@@ -202,12 +292,13 @@ def attn_apply(params: dict, x: Stream, ctx: MatmulContext, cfg: ModelConfig, *,
       - decode: ``kv_cache`` given, ``cache_pos`` scalar — writes the new
         K/V at ``cache_pos`` then attends over the cache.
       - paged decode/prefill (continuous batching): ``paged`` given —
-        ``kv_cache`` is a page pool and ``paged`` carries
-        {block_tables [B,MP], lens [B], new_counts [B]}; every row sits at
+        ``kv_cache`` is every layer's page pool [G, P, ...] and ``paged``
+        carries {layer, block_tables [B,MP], lens [B], new_counts [B]}
+        (``layer`` indexes the pool's G axis); every row sits at
         its own position (``positions`` is [B,S]), K/V are scattered into
         the row's pages and attention reads the gathered page stream.
       - flat paged (token-level batching): ``paged`` carries
-        {block_tables [B,MP], row_ids [W], q_pos [W]} and x is one
+        {layer, block_tables [B,MP], row_ids [W], q_pos [W]} and x is one
         ``[1, W]`` stream — per-position scatter, then the segment-masked
         ragged-attention op (kernels/ragged_attn) gathers each query's own
         row.
@@ -248,12 +339,13 @@ def attn_apply(params: dict, x: Stream, ctx: MatmulContext, cfg: ModelConfig, *,
         from repro.kernels.ragged_attn import ragged_attention
         with jax.named_scope("kv_update"):
             new_cache = flat_paged_kv_update(
-                kv_cache, k, v, block_tables=paged["block_tables"],
+                kv_cache, k, v, layer=paged["layer"],
+                block_tables=paged["block_tables"],
                 row_ids=paged["row_ids"], q_pos=paged["q_pos"])
         out = ragged_attention(
             q[0], new_cache["k_pages"], new_cache["v_pages"],
-            block_tables=paged["block_tables"], row_ids=paged["row_ids"],
-            q_pos=paged["q_pos"])[None]
+            layer=paged["layer"], block_tables=paged["block_tables"],
+            row_ids=paged["row_ids"], q_pos=paged["q_pos"])[None]
         out = ctx.constrain(out, (None, mdl, None)).reshape(b, sq, hq * dh)
         out = linear_apply(params["wo"], out, ctx, keep_packed=keep_packed,
                            tp="row")
@@ -261,7 +353,8 @@ def attn_apply(params: dict, x: Stream, ctx: MatmulContext, cfg: ModelConfig, *,
     if paged is not None:
         with jax.named_scope("kv_update"):
             new_cache, k, v, kv_len_mask = paged_kv_update(
-                kv_cache, k, v, block_tables=paged["block_tables"],
+                kv_cache, k, v, layer=paged["layer"],
+                block_tables=paged["block_tables"],
                 lens=paged["lens"], new_counts=paged["new_counts"])
     elif kv_cache is not None:
         # decode: insert this step's K/V at cache_pos, attend over the cache

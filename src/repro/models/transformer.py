@@ -196,7 +196,8 @@ def init_paged_caches(cfg: ModelConfig, num_pages: int, page_tokens: int,
     """Stacked [G, ...] caches for continuous-batching decode.
 
     Attention K/V lives in a shared paged pool indexed by per-request block
-    tables ([G, P, T, Hkv, dh]; page ids are shared across groups and
+    tables ([G, P, Hkv, T/f, f*dh], pages laid out by
+    :class:`attention.KVPageLayout`; page ids are shared across groups and
     pattern slots — one logical page holds a token range's KV for every
     attention layer).  Recurrent mixer state is O(1)/sequence and stays
     per-slot dense ([G, slots, ...])."""
@@ -230,19 +231,20 @@ def layers_apply(params_groups: dict, x: Stream, ctx: MatmulContext,
     for cross-attention) and decode (``caches`` stacked [G, ...]; whisper
     decode additionally passes per-layer precomputed ``cross_kv``; paged
     continuous-batching decode passes ``paged`` block-table state shared by
-    every group).  The paged mode is fully ragged per row — each row's
-    ``positions``/``new_counts`` place anywhere from 0 to S new tokens at
-    its own offset, which is what lets the serving engine fuse chunked
-    prefill and decode into one fixed-shape step (and what a speculative
-    verify step will reuse).  NOTE: only attention layers are inert on
-    padded row positions (their writes land in the trash page and the
-    causal mask hides them); mamba/rwkv scans carry state across every
-    position, so ragged multi-token rows are pure-attention-only — hybrids
-    keep exact-length monolithic prefill.
+    every group, and each group's attention reads and writes the stacked
+    page pools at its own layer index).  The paged mode is fully ragged
+    per row — each row's ``positions``/``new_counts`` place anywhere from
+    0 to S new tokens at its own offset, which is what lets the serving
+    engine fuse chunked prefill and decode into one fixed-shape step (and
+    what a speculative verify step will reuse).  NOTE: only attention
+    layers are inert on padded row positions (their writes land in the
+    trash page and the causal mask hides them); mamba/rwkv scans carry
+    state across every position, so ragged multi-token rows are
+    pure-attention-only — hybrids keep exact-length monolithic prefill.
     """
     period = pattern_period(cfg)
 
-    def apply_group(x, gp, gc, gkv):
+    def apply_group(x, gp, gc, gkv, paged):
         x = constrain_stream(x, ctx)
         new_gc = {}
         aux_g = dict(AUX_ZERO)
@@ -261,12 +263,33 @@ def layers_apply(params_groups: dict, x: Stream, ctx: MatmulContext,
     if caches is None:
         def body(carry, gp):
             x, aux_acc = carry
-            x, _, aux_g = apply_group(x, gp, None, None)
+            x, _, aux_g = apply_group(x, gp, None, None, None)
             return (x, {k: aux_acc[k] + aux_g[k] for k in aux_acc}), None
         if run.remat:
             body = jax.checkpoint(body)
         (x, aux), _ = jax.lax.scan(body, (x, dict(AUX_ZERO)), params_groups)
         return x, None, aux
+
+    if paged is not None:
+        # the page pools ride in the carry, each layer scattering into and
+        # reading its own [layer] slice in place: as scanned xs/ys every
+        # layer would slice its pool out and update it back into a fresh
+        # stacked buffer, moving the whole pool each step
+        pools, slot_states = _split_pools(caches)
+
+        def paged_body(carry, xs_):
+            x, pools = carry
+            gp, gc, layer = xs_
+            xo, ngc, _ = apply_group(x, gp, _join_pools(gc, pools), None,
+                                     {**paged, "layer": layer})
+            pools, ngc = _split_pools(ngc)
+            return (xo, pools), ngc
+
+        (x, pools), new_states = jax.lax.scan(
+            paged_body, (x, pools),
+            (params_groups, slot_states,
+             jnp.arange(cfg.n_layers // period, dtype=jnp.int32)))
+        return x, _join_pools(new_states, pools), dict(AUX_ZERO)
 
     xs = ((params_groups, caches) if cross_kv is None
           else (params_groups, caches, cross_kv))
@@ -274,11 +297,25 @@ def layers_apply(params_groups: dict, x: Stream, ctx: MatmulContext,
     def body(x, xs_):
         gp, gc = xs_[0], xs_[1]
         gkv = xs_[2] if len(xs_) == 3 else None
-        xo, ngc, _ = apply_group(x, gp, gc, gkv)
+        xo, ngc, _ = apply_group(x, gp, gc, gkv, None)
         return xo, ngc
 
     x, new_caches = jax.lax.scan(body, x, xs)
     return x, new_caches, dict(AUX_ZERO)
+
+
+def _split_pools(caches: dict):
+    """Paged caches ``{p<i>: {"kv": pool, ...}}`` -> (``{p<i>: pool}`` for
+    the attention slots, the per-slot recurrent state without them)."""
+    pools = {k: c["kv"] for k, c in caches.items() if "kv" in c}
+    rest = {k: {n: v for n, v in c.items() if n != "kv"}
+            for k, c in caches.items()}
+    return pools, rest
+
+
+def _join_pools(states: dict, pools: dict) -> dict:
+    return {k: ({**c, "kv": pools[k]} if k in pools else c)
+            for k, c in states.items()}
 
 
 # ---------------------------------------------------------------------------

@@ -105,26 +105,20 @@ class StepCostModel:
 
 def kv_page_bytes_per_token(caches, num_pages: int, page_tokens: int) -> float:
     """Bytes of paged K/V per cached token, summed over every page-pool
-    leaf — those with an adjacent ``(num_pages, page_tokens)`` dim pair
-    (``[layers, num_pages, page_tokens, heads, d_head]`` in the grouped
-    attention caches).  Per-slot recurrent state (no such pair) is
-    excluded: it is not gathered through the block table."""
-    total = 0.0
-    for leaf in _tree_leaves(caches):
-        shape = tuple(int(d) for d in getattr(leaf, "shape", ()))
-        paged = any(shape[i] == num_pages and shape[i + 1] == page_tokens
-                    for i in range(len(shape) - 1))
-        if paged:
-            nbytes = float(np.dtype(leaf.dtype).itemsize)
-            for d in shape:
-                nbytes *= d
-            total += nbytes / (num_pages * page_tokens)
-    return total
-
-
-def _tree_leaves(tree):
+    leaf — the ``*_pages`` leaves, ``[layers, num_pages, ...]`` with
+    ``page_tokens`` tokens a page (models/attention.py ``KVPageLayout``).
+    Per-slot recurrent state is excluded: it is not gathered through the
+    block table."""
     import jax
-    return jax.tree_util.tree_leaves(tree)
+    total = 0.0
+    for path, leaf in jax.tree_util.tree_leaves_with_path(caches):
+        if not jax.tree_util.keystr(path).endswith("_pages']"):
+            continue
+        nbytes = float(np.dtype(leaf.dtype).itemsize)
+        for d in leaf.shape:
+            nbytes *= int(d)
+        total += nbytes / (num_pages * page_tokens)
+    return total
 
 
 def _family_geometry(label: str, engine) -> tuple:

@@ -47,8 +47,13 @@ lands as whole tiles and a paused prefill's held pages stay valid KV
 invalidates them.
 
 Device-side pool arrays live inside the engine's cache pytree
-(``{"k_pages","v_pages"}: [G, P, T, Hkv, dh]``, built by
-``transformer.init_paged_caches``); this module owns the host-side
+(``{"k_pages","v_pages"}: [G, P, Hkv, T/f, f*dh]``, built by
+``transformer.init_paged_caches``): each page is head-major and
+lane-dense, ``f = gcd(128 // dh, T)`` consecutive tokens of one kv head
+side by side in a row of ``f*dh`` lanes (``f = 1`` at ``dh >= 128``), the
+layout ``models.attention.KVPageLayout`` owns.  The flat step updates the
+pool in place and the ragged-attention kernel reads it where it lies, by
+layer index and page id.  This module owns the host-side
 bookkeeping (allocator, per-request block tables) plus the pytree helpers
 that separate shared page pools from per-slot recurrent state.
 
@@ -401,7 +406,8 @@ def _copy_pages(caches, src, dst):
 copy_pages = jax.jit(_copy_pages, donate_argnums=(0,))
 copy_pages.__doc__ = """Device-side page copy for copy-on-write splits:
 duplicate page ``src``'s contents into ``dst`` in every ``*_pages`` pool
-leaf ([G, P, T, Hkv, dh]; page dim = axis 1) of the cache pytree, leaving
+leaf ([G, P, Hkv, T/f, f*dh], every layer's whole page of every kv head;
+page dim = axis 1, as ``KVPageLayout`` keeps it) of the cache pytree, leaving
 per-slot recurrent state untouched.  One jitted program per cache
 structure (the engine primes it at warmup), with the input donated so the
 pool is updated in place rather than doubled."""

@@ -33,6 +33,8 @@ from repro.configs import RunConfig, ShapeSpec, get_config, reduced_config
 from repro.kernels.ragged_attn.kernel import ragged_attention_kernel_call
 from repro.kernels.ragged_attn.ref import \
     ragged_attention_ref as ragged_attention_reference
+from repro.models import attention
+from repro.models.attention import KVPageLayout
 from repro.models.model import build_model
 from repro.serving.engine import Engine
 from repro.serving.scheduler import Request, finish_reason_for
@@ -287,21 +289,32 @@ def test_flat_width_ladder(smollm):
 # Pallas kernel vs reference oracle
 # ---------------------------------------------------------------------------
 
+def _pool(key, layers, pages, hkv, dh, t, dtype):
+    shape = (layers, pages) + KVPageLayout(hkv, dh, t).page_shape
+    return jax.random.normal(key, shape).astype(dtype)
+
+
+@pytest.mark.parametrize("layer", ["first", "last"])
 @pytest.mark.parametrize("hq,hkv,dh,dtype,tol", [
     (4, 2, 8, jnp.float32, 1e-5),
     # smollm2-135m's heads in bf16: both sides round once to bf16, so one
     # ulp (2^-6 at |x| < 4) is the most they may differ by
     (9, 3, 64, jnp.bfloat16, 2 ** -6),
-], ids=["4-2-8-f32", "9-3-64-bf16"])
-def test_ragged_kernel_matches_reference(hq, hkv, dh, dtype, tol):
+    # olmo-1b's head width: one token a 128-lane row
+    (4, 2, 128, jnp.float32, 1e-5),
+], ids=["4-2-8-f32", "9-3-64-bf16", "4-2-128-f32"])
+def test_ragged_kernel_matches_reference(hq, hkv, dh, dtype, tol, layer):
     """Interpret-mode Pallas kernel vs the jnp oracle on mixed segments:
-    a decode row, a mid-prefill chunk, a fresh prefill and -1 padding."""
+    a decode row, a mid-prefill chunk, a fresh prefill and -1 padding,
+    read from the first or the last layer of a stacked pool.  The decode
+    row and the chunk end on odd in-page offsets, so both lane halves of
+    a folded row are live."""
     key = jax.random.PRNGKey(0)
-    t, pages, mp, w = 8, 9, 3, 16
+    t, layers, pages, mp, w = 8, 3, 9, 3, 16
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (w, hq, dh)).astype(dtype)
-    k_pages = jax.random.normal(ks[1], (pages, t, hkv, dh)).astype(dtype)
-    v_pages = jax.random.normal(ks[2], (pages, t, hkv, dh)).astype(dtype)
+    k_pages = _pool(ks[1], layers, pages, hkv, dh, t, dtype)
+    v_pages = _pool(ks[2], layers, pages, hkv, dh, t, dtype)
     bt = np.asarray(jax.random.permutation(jax.random.PRNGKey(5),
                                            pages)[: 3 * mp],
                     np.int32).reshape(3, mp)
@@ -312,7 +325,8 @@ def test_ragged_kernel_matches_reference(hq, hkv, dh, dtype, tol):
     row_ids[0], q_pos[0] = 0, 17
     row_ids[1:6], q_pos[1:6] = 1, np.arange(8, 13)
     row_ids[6:10], q_pos[6:10] = 2, np.arange(4)
-    args = dict(block_tables=jnp.asarray(bt), row_ids=jnp.asarray(row_ids),
+    args = dict(layer=jnp.int32(0 if layer == "first" else layers - 1),
+                block_tables=jnp.asarray(bt), row_ids=jnp.asarray(row_ids),
                 q_pos=jnp.asarray(q_pos))
     ref = ragged_attention_reference(q, k_pages, v_pages, **args)
     out = ragged_attention_kernel_call(q, k_pages, v_pages, interpret=True,
@@ -321,6 +335,62 @@ def test_ragged_kernel_matches_reference(hq, hkv, dh, dtype, tol):
     np.testing.assert_allclose(np.asarray(out, np.float32)[row_ids >= 0],
                                np.asarray(ref, np.float32)[row_ids >= 0],
                                atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("dh", [64, 128])
+def test_flat_scatter_round_trip(dh):
+    """A token scattered at (layer, page, off) is the one the oracle and
+    the kernel read at that position.  Keys are zero, so each query
+    averages the values of its row's positions up to its own; values are
+    the writing stream position's id, so the average names what was read.
+    Row 1 writes odd offsets alone, and a second step writes the partner
+    of an earlier token: a folded row keeps the lanes the step does not
+    write."""
+    hkv, hq, t, layers, pages = 2, 4, 16, 2, 6
+    lay = KVPageLayout(hkv, dh, t)
+    bt = jnp.asarray([[3, 1], [5, 2]], jnp.int32)
+    zero = jnp.zeros((layers, pages) + lay.page_shape)
+    # step 1: row 0 writes positions 5..20 (pages 3 and 1), row 1 the odd
+    # positions 9 and 19; padding writes the trash page.  step 2: row 1
+    # writes position 8, beside the 9 of step 1
+    steps = [([0] * 16 + [1, 1, -1, -1], list(range(5, 21)) + [9, 19, 0, 0]),
+             ([1, -1, -1, -1], [8, 0, 0, 0])]
+    for layer in range(layers):
+        cache = {"k_pages": zero, "v_pages": zero}
+        written, base = {}, 0
+        for rows, pos in steps:
+            row_ids = jnp.asarray(rows, jnp.int32)
+            q_pos = jnp.asarray(pos, jnp.int32)
+            w = len(rows)
+            ids = base + jnp.arange(w, dtype=jnp.float32) + 1.0
+            base += w
+            v = jnp.broadcast_to(ids[None, :, None, None], (1, w, hkv, dh))
+            cache = attention.flat_paged_kv_update(
+                cache, jnp.zeros((1, w, hkv, dh)), v,
+                layer=jnp.int32(layer), block_tables=bt, row_ids=row_ids,
+                q_pos=q_pos)
+            written.update({(r, p): float(i) for r, p, i
+                            in zip(rows, pos, ids) if r >= 0})
+        vp = cache["v_pages"]
+        assert not np.asarray(vp[1 - layer]).any()   # other layer untouched
+        toks = lay.tokens(vp[layer])                 # [P, T, Hkv, dh]
+        for (r, p), i in written.items():
+            np.testing.assert_array_equal(
+                np.asarray(toks[int(bt[r, p // t]), p % t]), i)
+        keys = sorted(written)
+        row_ids = jnp.asarray([r for r, _ in keys], jnp.int32)
+        q_pos = jnp.asarray([p for _, p in keys], jnp.int32)
+        q = jnp.zeros((len(keys), hq, dh))
+        sel = dict(layer=jnp.int32(layer), block_tables=bt, row_ids=row_ids,
+                   q_pos=q_pos)
+        ref = ragged_attention_reference(q, cache["k_pages"], vp, **sel)
+        out = ragged_attention_kernel_call(q, cache["k_pages"], vp,
+                                           interpret=True, **sel)
+        want = [np.mean([written.get((r, j), 0.0) for j in range(p + 1)])
+                for r, p in keys]
+        for got in (ref, out):
+            np.testing.assert_allclose(np.asarray(got)[:, 0, 0], want,
+                                       rtol=1e-6)
 
 
 # ---------------------------------------------------------------------------

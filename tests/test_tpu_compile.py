@@ -3,14 +3,17 @@
 The TPU compiler is installed here and compiles for a topology that is
 described, not attached; it refuses what interpret mode accepts (tiling,
 VMEM, shape casts Mosaic cannot lay out).  Each case compiles one kernel of
-the serving path, or the flat step of the benchmark model, at real widths
+the serving path, or the flat step of a benchmark model, at real widths
 on one chip of a ``v5e:2x2`` topology and asserts the Pallas kernel is in
-the compiled program (``tpu_custom_call``).
+the compiled program (``tpu_custom_call``), or that the donated flat step
+never moves the KV pool.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, so every xdist worker must
 collect the same tests and only the one running this file may load it.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +29,7 @@ from repro.kernels.mmt4d.ops import mmt4d
 from repro.kernels.pack.ops import pack
 from repro.kernels.ragged_attn.kernel import ragged_attention_kernel_call
 from repro.kernels.unpack.ops import unpack
+from repro.models.attention import KVPageLayout
 from repro.models.model import build_model
 
 # serving geometry of chip_smoke.py: 4 slots, max_len 1024, 16-token pages
@@ -86,15 +90,17 @@ def _compiled_text(one_chip, fn, *shapes):
                          ids=[f"{a}-{hq}/{hkv}/{dh}-W{w}"
                               for a, hq, hkv, dh, w in RAGGED_CASES])
 def test_ragged_kernel_compiles(one_chip, arch, hq, hkv, dh, w, dtype):
-    def call(q, k, v, bt, row_ids, q_pos):
+    def call(q, k, v, bt, row_ids, q_pos, layer):
         return ragged_attention_kernel_call(
-            q, k, v, block_tables=bt, row_ids=row_ids, q_pos=q_pos)
+            q, k, v, layer=layer, block_tables=bt, row_ids=row_ids,
+            q_pos=q_pos)
 
-    pages = (NUM_PAGES, PAGE_TOKENS, hkv, dh)
+    pages = (2, NUM_PAGES) + KVPageLayout(hkv, dh, PAGE_TOKENS).page_shape
     text = _compiled_text(one_chip, call, ((w, hq, dh), dtype),
                           (pages, dtype), (pages, dtype),
                           ((SLOTS, MAX_PAGES), jnp.int32),
-                          ((w,), jnp.int32), ((w,), jnp.int32))
+                          ((w,), jnp.int32), ((w,), jnp.int32),
+                          ((), jnp.int32))
     assert "tpu_custom_call" in text
 
 
@@ -152,3 +158,62 @@ def test_flat_step_compiles_with_kernel(one_chip, monkeypatch):
         params, caches, i32(1, w), i32(SLOTS, MAX_PAGES), i32(w), i32(w),
         i32(SLOTS)).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+# the benchmark cells' serving sizes and a decode width each: (slots,
+# max_len, W).  At chip_smoke.py's 4 slots the pool is small enough for the
+# compiler to prefetch it between memory spaces (copy-start/copy-done), and
+# the step's own temp (the tied head's packed embedding) outweighs a layer
+# of pool, so neither assertion would say anything there.
+POOL_CASES = [("smollm2-135m", 128, 1024, 64), ("olmo-1b", 20, 2048, 32)]
+
+
+def _pool_moves(text: str, num_pages: int) -> list:
+    """Instructions of the compiled program that copy, slice or update a
+    tensor holding ``num_pages`` pages (``copy``, ``dynamic-slice`` and
+    ``dynamic-update-slice`` instructions, or fusions named for them)."""
+    moves = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.-]+) = \(?\w+\[([\d,]*)\]", line)
+        if m is None or str(num_pages) not in m.group(2).split(","):
+            continue
+        op = line.split(" = ", 1)[1]
+        if re.search(r"(^|[_.])(copy|dynamic-slice|dynamic-update-slice)"
+                     r"([_.]|$)", m.group(1)) or re.search(
+                r"\] (copy|copy-start|dynamic-slice|dynamic-update-slice)\(",
+                op):
+            moves.append(line.strip()[:160])
+    return moves
+
+
+@pytest.mark.parametrize("arch,slots,max_len,w", POOL_CASES,
+                         ids=[c[0] for c in POOL_CASES])
+def test_flat_step_keeps_pool_in_place(one_chip, monkeypatch, arch, slots,
+                                       max_len, w):
+    """The donated flat step (``jit_step("flat")``) of each benchmark model
+    at its cell's serving size updates the KV pool in place and the kernel
+    reads it where it lies: no copy, slice or update of a pool-sized
+    tensor, and less temp memory than one layer's K and V pool."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    run = RunConfig(param_dtype="bfloat16", compute_dtype="bfloat16",
+                    remat=False)
+    cfg = get_config(arch)
+    model = build_model(cfg, run, ShapeSpec("serve", max_len, slots,
+                                            "decode"))
+    max_pages = max_len // PAGE_TOKENS
+    num_pages = 1 + slots * max_pages
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda s: _spec(one_chip, s.shape, s.dtype), tree)
+    params = place(jax.eval_shape(lambda: prepack_params(
+        model.init(jax.random.PRNGKey(0)), model.ctx)))
+    caches = place(jax.eval_shape(
+        lambda: model.init_paged_cache(num_pages, PAGE_TOKENS, slots)))
+    i32 = lambda *s: _spec(one_chip, s, jnp.int32)  # noqa: E731
+    compiled = model.jit_step("flat").lower(
+        params, caches, i32(1, w), i32(slots, max_pages), i32(w), i32(w),
+        i32(slots)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _pool_moves(text, num_pages) == []
+    layer_pool = 2 * 2 * num_pages * PAGE_TOKENS * cfg.n_kv_heads * cfg.d_head
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_pool
