@@ -923,11 +923,14 @@ class Engine:
                   prefill_tokens: int, decode_tokens: int) -> np.ndarray:
         """One flat step; returns logits [K_out, V] at the flat ``idx``
         positions (K_out = slots * (spec_tokens+1 or 1)).  The token
-        counts label the ``engine.dispatch`` span."""
+        counts label the ``engine.dispatch`` span, with ``kv_pages``: the
+        (position, live page) pairs the ragged-attention kernel fetches."""
+        kv_pages = int(np.sum(q_pos[row_ids >= 0] // self.pool.page_tokens
+                              + 1))
         logits, self.caches = self._call(
             self._flat_step, self.caches, (token, bt, row_ids, q_pos, idx),
             width=token.shape[1], prefill_tokens=prefill_tokens,
-            decode_tokens=decode_tokens)
+            decode_tokens=decode_tokens, kv_pages=kv_pages)
         return logits[0]
 
     def _call(self, step_fn, caches, inputs, **args):

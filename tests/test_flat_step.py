@@ -30,7 +30,8 @@ import numpy as np
 import pytest
 
 from repro.configs import RunConfig, ShapeSpec, get_config, reduced_config
-from repro.kernels.ragged_attn.kernel import ragged_attention_kernel_call
+from repro.kernels.ragged_attn.kernel import (block_pages,
+                                              ragged_attention_kernel_call)
 from repro.kernels.ragged_attn.ref import \
     ragged_attention_ref as ragged_attention_reference
 from repro.models import attention
@@ -294,37 +295,65 @@ def _pool(key, layers, pages, hkv, dh, t, dtype):
     return jax.random.normal(key, shape).astype(dtype)
 
 
+# segment mixes: page tokens, block table width MP, and each segment's
+# (row, first q_pos, positions); row -1 is padding.  SHORT: a decode row at
+# 17, a mid-prefill chunk at 8..12, a fresh 4-token prefill.  BLOCKS, at
+# 16-token pages where the kernel copies 8-page blocks and MP 27 is not a
+# multiple of 8: a decode row whose last live page (19) ends mid-block, a
+# pad, a decode row whose last live page (15) ends block 1, a chunk at
+# 120..140 across the block boundary at page 8, and a decode row on the
+# table's last page (26, a 3-page block); tails of the table are page 0
+SHORT = (8, 3, [(0, 17, 1), (1, 8, 5), (2, 0, 4)])
+BLOCKS = (16, 27, [(0, 309, 1), (-1, 0, 1), (1, 255, 1), (2, 120, 21),
+                   (3, 431, 1)])
+
+
 @pytest.mark.parametrize("layer", ["first", "last"])
-@pytest.mark.parametrize("hq,hkv,dh,dtype,tol", [
-    (4, 2, 8, jnp.float32, 1e-5),
+@pytest.mark.parametrize("hq,hkv,dh,dtype,tol,mix", [
+    (4, 2, 8, jnp.float32, 1e-5, SHORT),
     # smollm2-135m's heads in bf16: both sides round once to bf16, so one
     # ulp (2^-6 at |x| < 4) is the most they may differ by
-    (9, 3, 64, jnp.bfloat16, 2 ** -6),
+    (9, 3, 64, jnp.bfloat16, 2 ** -6, SHORT),
     # olmo-1b's head width: one token a 128-lane row
-    (4, 2, 128, jnp.float32, 1e-5),
-], ids=["4-2-8-f32", "9-3-64-bf16", "4-2-128-f32"])
-def test_ragged_kernel_matches_reference(hq, hkv, dh, dtype, tol, layer):
-    """Interpret-mode Pallas kernel vs the jnp oracle on mixed segments:
-    a decode row, a mid-prefill chunk, a fresh prefill and -1 padding,
-    read from the first or the last layer of a stacked pool.  The decode
-    row and the chunk end on odd in-page offsets, so both lane halves of
-    a folded row are live."""
+    (4, 2, 128, jnp.float32, 1e-5, SHORT),
+    (9, 3, 64, jnp.bfloat16, 2 ** -6, BLOCKS),
+    (16, 16, 128, jnp.float32, 1e-5, BLOCKS),
+], ids=["4-2-8-f32", "9-3-64-bf16", "4-2-128-f32", "9-3-64-bf16-blocks",
+        "16-16-128-f32-blocks"])
+def test_ragged_kernel_matches_reference(hq, hkv, dh, dtype, tol, mix, layer):
+    """Interpret-mode Pallas kernel vs the jnp oracle on mixed segments
+    and -1 padding, read from the first or the last layer of a stacked
+    pool.  Decode rows and chunks end on odd in-page offsets, so both lane
+    halves of a folded row are live; BLOCKS spans several page blocks."""
+    t, mp, segs = mix
+    w = 16 if mix is SHORT else 32
     key = jax.random.PRNGKey(0)
-    t, layers, pages, mp, w = 8, 3, 9, 3, 16
+    n_rows = max(r for r, _, _ in segs) + 1
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (w, hq, dh)).astype(dtype)
-    k_pages = _pool(ks[1], layers, pages, hkv, dh, t, dtype)
-    v_pages = _pool(ks[2], layers, pages, hkv, dh, t, dtype)
-    bt = np.asarray(jax.random.permutation(jax.random.PRNGKey(5),
-                                           pages)[: 3 * mp],
-                    np.int32).reshape(3, mp)
-    # row 0: one decode token at pos 17; row 1: 5-token chunk at 8..12;
-    # row 2: fresh 4-token prefill; rest: padding
     row_ids = np.full(w, -1, np.int32)
     q_pos = np.zeros(w, np.int32)
-    row_ids[0], q_pos[0] = 0, 17
-    row_ids[1:6], q_pos[1:6] = 1, np.arange(8, 13)
-    row_ids[6:10], q_pos[6:10] = 2, np.arange(4)
+    pos = 0
+    for row, first, n in segs:
+        row_ids[pos:pos + n], q_pos[pos:pos + n] = row, first + np.arange(n)
+        pos += n
+    if mix is SHORT:
+        # every slot of the table a real page, shuffled
+        layers, pages = 3, 9
+        bt = np.asarray(jax.random.permutation(jax.random.PRNGKey(5),
+                                               pages)[: n_rows * mp],
+                        np.int32).reshape(n_rows, mp)
+    else:
+        # each row's live pages shuffled over the pool, page 0 after them
+        live = [int(q_pos[row_ids == r].max()) // t + 1 for r in range(n_rows)]
+        layers, pages = 2, 1 + sum(live)
+        perm = np.asarray(jax.random.permutation(jax.random.PRNGKey(5),
+                                                 pages - 1), np.int32) + 1
+        bt = np.zeros((n_rows, mp), np.int32)
+        for r, n in enumerate(live):
+            bt[r, :n], perm = perm[:n], perm[n:]
+    k_pages = _pool(ks[1], layers, pages, hkv, dh, t, dtype)
+    v_pages = _pool(ks[2], layers, pages, hkv, dh, t, dtype)
     args = dict(layer=jnp.int32(0 if layer == "first" else layers - 1),
                 block_tables=jnp.asarray(bt), row_ids=jnp.asarray(row_ids),
                 q_pos=jnp.asarray(q_pos))
@@ -335,6 +364,22 @@ def test_ragged_kernel_matches_reference(hq, hkv, dh, dtype, tol, layer):
     np.testing.assert_allclose(np.asarray(out, np.float32)[row_ids >= 0],
                                np.asarray(ref, np.float32)[row_ids >= 0],
                                atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("hkv,dh,dtype,mp,want", [
+    (3, 64, jnp.bfloat16, 64, 8),       # smollm2-135m's cell
+    (16, 128, jnp.bfloat16, 128, 8),    # olmo-1b's cell
+    (16, 128, jnp.float32, 128, 8),     # 4 MiB of buffers exactly
+    (32, 256, jnp.float32, 128, 2),     # a 512 KiB page
+    (3, 64, jnp.bfloat16, 3, 3),        # a table narrower than a block
+])
+def test_block_pages_rule(hkv, dh, dtype, mp, want):
+    """K, the pages a kernel block copies, from the shapes alone: about
+    128 tokens of 16-token pages, fewer where the page buffers would pass
+    the VMEM budget, never more than the table holds."""
+    lay = KVPageLayout(hkv, dh, 16)
+    page_bytes = int(np.prod(lay.page_shape)) * jnp.dtype(dtype).itemsize
+    assert block_pages(page_bytes, 16, mp) == want
 
 
 @pytest.mark.parametrize("dh", [64, 128])

@@ -22,8 +22,9 @@ Contracts covered:
     explicit per-drain reset;
   - under ``jax.profiler`` each flat step is one ``engine.step`` span
     holding its six phases in order, ``engine.dispatch`` carries the
-    step's planned width and token counts, and the traced drain is
-    token-identical to an untraced one with zero post-warmup retraces.
+    step's planned width, token counts and kernel page fetches, and the
+    traced drain is token-identical to an untraced one with zero
+    post-warmup retraces.
 """
 
 import json
@@ -224,11 +225,19 @@ def profiled(smollm, tmp_path_factory):
     def spy(decode_counts, budget):
         segs = plan_segments(decode_counts, budget)
         total = sum(n for _, _, n in segs)
+        # each fed position fetches its row's pages up to its own
+        t = eng.pool.page_tokens
+        kv_pages = 0
+        for slot, kind, n in segs:
+            req = eng.scheduler.running[slot]
+            first = req.len if kind == "decode" else req.prefill_cursor
+            kv_pages += sum((first + j) // t + 1 for j in range(n))
         plans.append({"width": eng._flat_shape(total),
                       "prefill_tokens": sum(n for _, k, n in segs
                                             if k == "prefill"),
                       "decode_tokens": sum(n for _, k, n in segs
-                                           if k == "decode")})
+                                           if k == "decode"),
+                      "kv_pages": kv_pages})
         return segs
     eng.scheduler.plan_segments = spy
     before = dict(m.trace_counts)
@@ -269,6 +278,8 @@ def test_dispatch_args_are_the_planned_step(profiled):
     assert got == plans
     assert any(p["prefill_tokens"] == 0 for p in plans)
     assert any(p["prefill_tokens"] and p["decode_tokens"] for p in plans)
+    assert all(p["kv_pages"] >= p["prefill_tokens"] + p["decode_tokens"]
+               for p in plans)
     copies = [e[3]["bytes"] for e in spans if e[0] == "engine.logits_to_host"]
     assert copies and all(b > 0 for b in copies)
 

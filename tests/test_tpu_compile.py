@@ -48,9 +48,14 @@ def _head_geometries():
     return [(arch, *g) for g, arch in geos.items()]
 
 
-RAGGED_CASES = ([(arch, hq, hkv, dh, 64) for arch, hq, hkv, dh in
-                 _head_geometries()]
-                + [("smollm2-135m", 9, 3, 64, 4)])
+# (arch, hq, hkv, dh, W, slots, MP): every head geometry at chip_smoke.py's
+# serving size, then each benchmark cell's own (olmo-1b: 20 slots x 128
+# pages, smollm2-135m: 128 x 64) at its decode width and its widest
+RAGGED_CASES = ([(arch, hq, hkv, dh, 64, SLOTS, MAX_PAGES)
+                 for arch, hq, hkv, dh in _head_geometries()]
+                + [("smollm2-135m", 9, 3, 64, 4, SLOTS, MAX_PAGES)]
+                + [("olmo-1b", 16, 16, 128, w, 20, 128) for w in (32, 512)]
+                + [("smollm2-135m", 9, 3, 64, w, 128, 64) for w in (16, 512)])
 
 
 @pytest.fixture(scope="module")
@@ -84,21 +89,29 @@ def _compiled_text(one_chip, fn, *shapes):
         .compile().as_text()
 
 
+def _ragged_id(arch, hq, hkv, dh, w, slots, mp):
+    size = "" if (slots, mp) == (SLOTS, MAX_PAGES) else f"-{slots}x{mp}"
+    return f"{arch}-{hq}/{hkv}/{dh}-W{w}{size}"
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
-@pytest.mark.parametrize("arch,hq,hkv,dh,w", RAGGED_CASES,
-                         ids=[f"{a}-{hq}/{hkv}/{dh}-W{w}"
-                              for a, hq, hkv, dh, w in RAGGED_CASES])
-def test_ragged_kernel_compiles(one_chip, arch, hq, hkv, dh, w, dtype):
+@pytest.mark.parametrize("arch,hq,hkv,dh,w,slots,mp", RAGGED_CASES,
+                         ids=[_ragged_id(*c) for c in RAGGED_CASES])
+def test_ragged_kernel_compiles(one_chip, arch, hq, hkv, dh, w, slots, mp,
+                                dtype):
+    """The kernel at a pool of two layers of ``1 + slots * MP`` pages: its
+    page blocks, hand-copied from the pool in HBM, fit VMEM."""
     def call(q, k, v, bt, row_ids, q_pos, layer):
         return ragged_attention_kernel_call(
             q, k, v, layer=layer, block_tables=bt, row_ids=row_ids,
             q_pos=q_pos)
 
-    pages = (2, NUM_PAGES) + KVPageLayout(hkv, dh, PAGE_TOKENS).page_shape
+    pages = (2, 1 + slots * mp) + KVPageLayout(hkv, dh,
+                                               PAGE_TOKENS).page_shape
     text = _compiled_text(one_chip, call, ((w, hq, dh), dtype),
                           (pages, dtype), (pages, dtype),
-                          ((SLOTS, MAX_PAGES), jnp.int32),
+                          ((slots, mp), jnp.int32),
                           ((w,), jnp.int32), ((w,), jnp.int32),
                           ((), jnp.int32))
     assert "tpu_custom_call" in text
