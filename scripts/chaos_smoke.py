@@ -23,7 +23,7 @@ import numpy as np
 
 
 CONFIGS = {
-    "chunked": dict(chunk_tokens=8, flat=False),
+    "flat-cache": dict(chunk_tokens=8, prefix_cache=True),
     "flat-spec-cache": dict(chunk_tokens=8, spec_tokens=3,
                             prefix_cache=True),
 }

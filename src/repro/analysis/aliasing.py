@@ -119,7 +119,7 @@ def _attention_unit_walkers(engine):
          S((), i32)),
         {0: lambda p: "pages" if "_pages" in p else None,
          3: "block_tables", 4: "validity", 5: "validity"}), 2))
-    if engine.flat:
+    if engine.chunked:
         w = engine._flat_shapes()[0]
         kvf = S((1, w, cfg.n_kv_heads, cfg.d_head), dt)
         out.append(("flat_paged_kv_update", taint_step(
@@ -154,7 +154,7 @@ def lint_engine_aliasing(engine, label: str = "engine") -> List[Finding]:
     b, mp = engine.slots, engine.max_pages
     cache_role = {1: lambda p: "pages" if "_pages" in p else None}
     # one pool K + one pool V scatter per layer scan body = 2 sites
-    if engine.flat:
+    if engine.chunked:
         w = engine._flat_shapes()[0]
         walker = taint_step(
             model.flat_decode_step,
@@ -164,13 +164,12 @@ def lint_engine_aliasing(engine, label: str = "engine") -> List[Finding]:
         f.extend(lint_kv_writes(walker, f"{here} flat_decode_step[1,{w}]",
                                 expect_writes=2))
     else:
-        s = engine.chunk_tokens if engine.chunked else 1
         walker = taint_step(
             model.paged_decode_step,
-            (params, caches, S((b, s), i32), S((b, mp), i32),
+            (params, caches, S((b, 1), i32), S((b, mp), i32),
              S((b,), i32), S((b,), i32), None),
             {**cache_role, 3: "block_tables", 4: "validity", 5: "validity"})
-        f.extend(lint_kv_writes(walker, f"{here} paged_decode_step[{b},{s}]",
+        f.extend(lint_kv_writes(walker, f"{here} paged_decode_step[{b},1]",
                                 expect_writes=2))
     return f
 

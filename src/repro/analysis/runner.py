@@ -3,12 +3,12 @@
 ``run_all`` is what ``scripts/analyze.py`` (and therefore
 ``scripts/tier1.sh --analyze``) executes: the AST lint over the serving
 and kernel trees, then — for each engine configuration the serving stack
-actually ships (monolithic, chunked dense, flat, flat+speculative,
-chunked+prefix-cache) — the shape-ladder linter, the KV-write aliasing
-pass, and (with ``traffic=True``) a sanitized warm drain followed by the
-recompile-hazard report and the pool-ledger audit.  One reduced model
-backs every engine, so jit programs are shared and the whole matrix runs
-in test time.
+actually ships (monolithic, monolithic+speculative, flat,
+flat+speculative, flat+prefix-cache) — the shape-ladder linter, the
+KV-write aliasing pass, and (with ``traffic=True``) a sanitized warm
+drain followed by the recompile-hazard report and the pool-ledger
+audit.  One reduced model backs every engine, so jit programs are
+shared and the whole matrix runs in test time.
 """
 
 from __future__ import annotations
@@ -24,14 +24,14 @@ from repro.analysis.report import AnalysisReport
 __all__ = ["build_model", "analyze_engine", "run_all", "CONFIG_MATRIX"]
 
 # every serving configuration family the repo ships; chunk/budget values
-# are reduced-scale but exercise full ladders (chunk ladder has >1 rung,
-# flat ladder has cap + sub-widths)
+# are reduced-scale but exercise full ladders (the flat ladder has cap +
+# sub-widths, the monolithic one its prefill buckets and verify shape)
 CONFIG_MATRIX = [
     ("monolithic", dict()),
-    ("chunked-dense", dict(chunk_tokens=16, flat=False)),
+    ("monolithic-spec", dict(spec_tokens=2)),
     ("flat", dict(chunk_tokens=16)),
     ("flat-spec", dict(chunk_tokens=16, spec_tokens=2)),
-    ("chunked-prefix", dict(chunk_tokens=16, flat=False, prefix_cache=True)),
+    ("flat-prefix", dict(chunk_tokens=16, prefix_cache=True)),
 ]
 
 

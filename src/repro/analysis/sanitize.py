@@ -64,15 +64,10 @@ class StepSanitizer:
         self.cancels_checked = 0   # quarantine/cancel page audits
         self.paged_widths: Optional[Set[int]] = self._declared_paged_widths()
         self.flat_widths: Optional[Set[int]] = (
-            set(engine._flat_shapes()) if engine.flat else None)
+            set(engine._flat_shapes()) if engine.chunked else None)
 
     def _declared_paged_widths(self) -> Optional[Set[int]]:
         eng = self.engine
-        if eng.chunked:
-            widths = set(eng._chunk_shapes()) | {1}
-            if eng.spec_tokens is not None:
-                widths.add(eng.spec_tokens + 1)
-            return widths
         if eng._bucket == 1:
             return None            # hybrids prefill at exact lengths
         widths = {1}

@@ -6,11 +6,10 @@ changes, and the zero-retrace contract holds only if every runtime shape
 is a member of the warmed ladder.  This pass checks that contract twice:
 
 * **Ladder algebra** (no tracing): re-derive each declared ladder from
-  the scheduler contract — chunk ladder = ``chunk_tokens`` halved to
-  ``m_r``; flat ladder = ``m_r``-aligned budget cap plus the powers of
-  two of ``m_r`` below it; monolithic prefill buckets = geometric
-  ``m_r``-multiples — and diff it against what the engine actually
-  computes (`_chunk_shapes`/`_flat_shapes`/`_prefill_bucket`).  A
+  the scheduler contract — flat ladder = ``m_r``-aligned budget cap
+  plus the powers of two of ``m_r`` below it; monolithic prefill
+  buckets = geometric ``m_r``-multiples — and diff it against what the
+  engine actually computes (`_flat_shapes`/`_prefill_bucket`).  A
   drifted implementation (e.g. a mis-aligned ``chunk_tokens`` hacked in
   after construction) is caught here with the exact offending width.
 
@@ -59,7 +58,7 @@ def step_families(engine) -> List[Tuple[str, object, tuple]]:
     S = jax.ShapeDtypeStruct
     b, mp = engine.slots, engine.max_pages
     fams = []
-    if engine.flat:
+    if engine.chunked:
         k1s = [1] + ([engine.spec_tokens + 1]
                      if engine.spec_tokens is not None else [])
         for w in engine._flat_shapes():
@@ -67,18 +66,6 @@ def step_families(engine) -> List[Tuple[str, object, tuple]]:
                 fams.append((f"flat[1,{w}]/k{k1}", model.flat_decode_step,
                              (params, caches, S((1, w), i32), S((b, mp), i32),
                               S((w,), i32), S((w,), i32), S((b * k1,), i32))))
-        return fams
-    if engine.chunked:
-        for s in engine._chunk_shapes() + [1]:
-            fams.append((f"chunk[{b},{s}]", model.paged_decode_step,
-                         (params, caches, S((b, s), i32), S((b, mp), i32),
-                          S((b,), i32), S((b,), i32), None)))
-        if engine.spec_tokens is not None:
-            for s in engine._chunk_shapes():
-                fams.append((f"chunk[{b},{s}]/verify", model.paged_decode_step,
-                             (params, caches, S((b, s), i32), S((b, mp), i32),
-                              S((b,), i32), S((b,), i32),
-                              S((b, engine.spec_tokens + 1), i32))))
         return fams
     # monolithic: geometric prefill buckets (batch-1 slot view) + decode
     if engine._bucket > 1:
@@ -137,15 +124,6 @@ def _declared_flat_ladder(engine) -> set:
     return ladder
 
 
-def _declared_chunk_ladder(engine) -> set:
-    m_r = engine._bucket
-    ladder, c = {engine.chunk_tokens}, engine.chunk_tokens
-    while c % 2 == 0 and c // 2 >= m_r and (c // 2) % m_r == 0:
-        c //= 2
-        ladder.add(c)
-    return ladder
-
-
 def lint_engine_shapes(engine, label: str = "engine", *,
                        trace: bool = True,
                        max_traces: Optional[int] = None) -> List[Finding]:
@@ -173,25 +151,12 @@ def lint_engine_shapes(engine, label: str = "engine", *,
                              f"token_budget={engine.token_budget} < m_r="
                              f"{m_r}: plan_chunks rounds grants down to "
                              f"the tile, so prefill could never advance"))
-        declared = _declared_chunk_ladder(engine)
-        actual = set(engine._chunk_shapes())
-        for c in sorted(actual):
-            if c % m_r != 0:
-                f.append(Finding(_PASS, "chunk-align", here,
-                                 f"ladder shape {c} is not m_r-aligned "
-                                 f"(m_r={m_r})",
-                                 detail={"shape": c, "m_r": m_r}))
-        if actual != declared and engine.chunk_tokens % m_r == 0:
-            f.append(Finding(_PASS, "chunk-ladder", here,
-                             f"chunk ladder {sorted(actual)} != declared "
-                             f"geometric ladder {sorted(declared)}"))
         if (engine.spec_tokens is not None
                 and engine.chunk_tokens < engine.spec_tokens + 1):
             f.append(Finding(_PASS, "verify-width", here,
                              f"chunk_tokens={engine.chunk_tokens} cannot "
                              f"hold the [{engine.spec_tokens + 1}]-wide "
                              f"verify row"))
-    if engine.flat:
         declared = _declared_flat_ladder(engine)
         actual = set(engine._flat_shapes())
         for w in sorted(actual):
@@ -215,7 +180,7 @@ def lint_engine_shapes(engine, label: str = "engine", *,
                                  f"_flat_shape({n}) = {w}, expected the "
                                  f"smallest ladder member >= {n} "
                                  f"({fits[0] if fits else '??'})"))
-    if not engine.chunked and m_r > 1:
+    elif m_r > 1:
         cap = round_up(engine.scheduler.max_len, m_r)
         l, seen = m_r, set()
         while True:

@@ -49,8 +49,8 @@ segment planning), ``engine.pack`` (the step's input arrays),
 ``engine.device_wait`` (until the logits are ready),
 ``engine.logits_to_host`` (the copy; arg ``bytes``) and
 ``engine.commit`` (picks, cursor advance, cache insert, finishing).
-The flat policy takes all six; the chunked and monolithic policies the
-three around their device calls.  With no profiler session running a
+The flat policy takes all six; the monolithic policy the three around
+its device calls.  With no profiler session running a
 span costs about a microsecond.
 
 **Component instants**: ``cow`` (copy-on-write page split),

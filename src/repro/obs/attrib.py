@@ -5,9 +5,9 @@ shapes — and therefore step cost — are known before execution.  This
 module joins the repo's two halves of that story.  At ``Engine.warmup()``
 time (and **only** then — the warmup-only contract below) it builds a
 :class:`StepCostModel`: for every compiled shape family on the engine's
-ladder (monolithic prefill buckets, chunked widths, flat widths, verify
-widths — the exact enumeration :func:`repro.analysis.shapes.step_families`
-derives from the warmup loop), the step function is lowered with
+ladder (monolithic prefill buckets, flat widths, verify widths — the
+exact enumeration :func:`repro.analysis.shapes.step_families` derives
+from the warmup loop), the step function is lowered with
 ``ShapeDtypeStruct`` stand-ins and compiled, XLA's ``cost_analysis()`` is
 normalized via :func:`repro.roofline.hlo_cost.xla_cost_dict`, the
 while-aware HLO parse re-derives dot FLOPs and HBM bytes, and the result
@@ -59,7 +59,7 @@ __all__ = ["FamilyCost", "StepCostModel", "build_cost_model",
 class FamilyCost:
     """Predicted cost of one compiled step family (one ladder shape)."""
 
-    label: str                 # e.g. "flat[1,64]/k1", "chunk[4,16]/verify"
+    label: str                 # e.g. "flat[1,64]/k1", "verify[4,3]"
     width: int                 # padded token positions per step (the grid)
     flops: float               # while-aware dot FLOPs per step
     hbm_bytes: float           # while-aware HBM traffic per step
@@ -124,7 +124,7 @@ def kv_page_bytes_per_token(caches, num_pages: int, page_tokens: int) -> float:
 def _family_geometry(label: str, engine) -> tuple:
     """(padded token positions, gathering rows) of a family, parsed from
     its label — the same grammar ``analysis.shapes.step_families`` emits:
-    ``flat[1,W]/kK`` | ``chunk[B,S](/verify)`` | ``prefill[1,L]`` |
+    ``flat[1,W]/kK`` | ``prefill[1,L]`` |
     ``decode[B,1]`` | ``verify[B,K]``."""
     dims = label.split("[", 1)[1].split("]", 1)[0]
     a, b = (int(x) for x in dims.split(","))

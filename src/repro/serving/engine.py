@@ -25,34 +25,32 @@ and a FCFS :class:`~repro.serving.scheduler.Scheduler`.  Per engine step:
   4. eviction: finished requests release slot + pages immediately.
 
 With ``chunk_tokens`` set (pure-attention models), prefill and decode fuse
-into a **single ragged step under a per-step token budget**: the batch is
-the fixed shape ``[slots, c]`` whenever any slot is prefilling — ``c``
-drawn from a short geometric ladder ``chunk_tokens, chunk_tokens/2, ..
-m_r`` sized to the step's largest chunk — and ``[slots, 1]`` otherwise
-(``log2(chunk/m_r)+2`` compiled shapes, still below the monolithic
-policy's ``log2`` prompt buckets), and every active row contributes between 1
-token (decoding) and ``chunk_tokens`` (prefilling) via per-row
-``new_counts``/positions — the paper's fixed-shape-grid argument (fix the
-tile grid once, let occupancy vary) applied to the serving step.  A long
-(or recompute-folded, hence unbounded) admission is spread across steps at
-``chunk_tokens`` per step and **never stalls running decodes** — the
-Sarathi-style chunked prefill ROADMAP asks for; inter-token latency during
-an admission is bounded by one fused-step time instead of one full-prompt
-prefill.  Chunk sizes are rounded up to the layout's ``m_r`` so chunk
-writes land on whole microkernel tiles, like the (``m_r``-aligned) pages
-they fill.  The same ragged multi-position row is the verify-step
+into a **single flat step under a per-step token budget**: one ``[1, W]``
+token stream — ``W`` drawn from a short geometric ladder of ``m_r``
+multiples up to the budget, sized to the step's real token count — in which
+every active row is a contiguous segment of between 1 token (decoding) and
+``chunk_tokens`` (prefilling), addressed by per-position row ids and
+positions (:meth:`Engine._step_flat`) — the paper's fixed-shape-grid
+argument (fix the tile grid once, let occupancy vary) applied to the
+serving step. A long (or recompute-folded, hence unbounded) admission is
+spread across steps at ``chunk_tokens`` per step and **never stalls running
+decodes** — the Sarathi-style chunked prefill ROADMAP asks for; inter-token
+latency during an admission is bounded by one fused-step time instead of
+one full-prompt prefill. Chunk sizes are rounded up to the layout's ``m_r``
+so chunk writes land on whole microkernel tiles, like the (``m_r``-aligned)
+pages they fill. The same ragged multi-position segment is the verify-step
 primitive speculative decode needs (score k draft tokens in one step).
-Monolithic prefill (``chunk_tokens=None``, the default) and ``eager=True``
-remain the PR-1/2 baseline policies for the benchmark A/B; recurrent-mixer
-families (ssm/rwkv/hybrid) always use them — a scan carries state through
-*every* row position, so padded chunk rows are not inert for them.
+Monolithic prefill (``chunk_tokens=None``, the default) is the reference
+the flat step is tested against; recurrent-mixer families (ssm/rwkv/
+hybrid) always use it — a scan carries state through *every* row position,
+so padded chunk rows are not inert for them.
 
 With ``spec_tokens=k`` (pure-attention models) the engine decodes
 **speculatively**: a pluggable :class:`~repro.serving.speculative.Drafter`
 proposes up to ``k`` guesses per decoding row, the row feeds
-``[fed-back token, d_1 .. d_k]`` through the same fixed-shape paged step
-(``new_counts`` = 1 + draft length — per-row draft lengths ride the ragged
-step exactly like per-row chunk lengths, zero new traces), ``logits_idx``
+``[fed-back token, d_1 .. d_k]`` through the step that serves it (a
+``1 + draft length`` segment of the flat step, or a row of the monolithic
+``[slots, k+1]`` verify shape — zero new traces either way), ``logits_idx``
 reads the target logits at every draft position from that one call, and
 the acceptance rule in :mod:`repro.serving.speculative` keeps outputs
 token-identical to the non-speculative engine — greedy and sampled — while
@@ -129,7 +127,6 @@ class Engine:
                  page_tokens: int = 16, num_pages: Optional[int] = None,
                  eager: bool = False, watermark_pages: int = 1,
                  chunk_tokens: Optional[int] = None,
-                 flat: Optional[bool] = None,
                  token_budget: Optional[int] = None,
                  spec_tokens: Optional[int] = None,
                  drafter: Optional[Drafter] = None,
@@ -161,9 +158,6 @@ class Engine:
         self.continuous = model.cfg.family not in _STATIC_FAMILIES
         self._next_rid = 0
         if not self.continuous:
-            assert not flat, \
-                f"{model.cfg.family} serves via generate_static; the flat " \
-                f"token-level step needs the continuous paged path"
             assert chunk_tokens is None, \
                 f"{model.cfg.family} serves via generate_static; chunked " \
                 f"prefill needs the continuous paged path"
@@ -196,21 +190,11 @@ class Engine:
                                round_up(max_len, layout.m_r))
         self.chunk_tokens = chunk_tokens
         self.chunked = chunk_tokens is not None
-        # flat token-level batching (the default whenever chunking is on):
-        # the fused step becomes one [1, W] m_r-packed token stream with
-        # per-position row ids — a decode row costs its real 1+k positions
-        # instead of a padded chunk-width row.  flat=False keeps the dense
-        # [slots, chunk] step as the A/B baseline.
-        self.flat = self.chunked if flat is None else bool(flat)
-        if self.flat:
-            assert self.chunked, \
-                "flat=True needs chunk_tokens: the flat token-level step " \
-                "rides the chunked scheduler (segments are its chunks)"
-        # the fused step is dense, so its device cost is set by the SHAPE
-        # (slots x chunk_tokens), not by how many of those positions carry
-        # tokens — the rational default budget is therefore shape-limited
-        # (throttling below it wastes padded compute); pass a smaller
-        # token_budget to bound page-allocation raggedness instead
+        # the default budget lets every slot carry a whole chunk in one
+        # flat step, so it never holds a prefill back while slots could
+        # take it; the flat width ladder is capped at the budget, so pass
+        # a smaller token_budget to bound the widest step (and its
+        # latency) instead
         self.token_budget = (token_budget if token_budget is not None
                              else max(1, self.slots * (chunk_tokens or 1)))
         assert self.token_budget >= 1
@@ -274,7 +258,7 @@ class Engine:
         self._spec_disabled = False
         self._spec_auto_disables = 0
         # speculative decode (spec_tokens=k): every decode row may carry
-        # 1 + k positions through the same fused ragged step
+        # 1 + k positions through the same batched step
         self.spec_tokens = spec_tokens
         self.drafter: Optional[Drafter] = None
         if spec_tokens is not None:
@@ -331,7 +315,7 @@ class Engine:
             self.caches = jax.device_put(self.caches,
                                          sharding.named(mesh, specs))
         self._paged_step = model.jit_step("paged")
-        self._flat_step = model.jit_step("flat") if self.flat else None
+        self._flat_step = model.jit_step("flat") if self.chunked else None
         # opt-in runtime sanitizer (analysis.sanitize): wraps the jitted
         # steps with host-side pool-write contract checks — every written
         # page private (ref == 1), in range, never the trash page, and
@@ -466,7 +450,7 @@ class Engine:
                 "itl_p99_s": lat["itl_s"]["p99"],
                 "e2e_p99_s": lat["e2e_s"]["p99"],
             }
-        if self.flat:
+        if self.chunked:
             fs = max(1, self._flat_steps)
             out["flat"] = {
                 "token_budget": self.token_budget,
@@ -474,8 +458,7 @@ class Engine:
                 "mean_tokens": self._flat_tokens / fs,
                 "mean_width": self._flat_width / fs,
                 # real tokens per compiled position: the padding tax the
-                # flat layout pays (1.0 = none; the dense [slots, chunk]
-                # grid pays slots*chunk/real)
+                # flat layout pays (1.0 = none)
                 "fill": self._flat_tokens / max(1, self._flat_width),
             }
         if self.spec_tokens is not None:
@@ -544,14 +527,15 @@ class Engine:
              seed: int = 0) -> List[Request]:
         """One engine step: admit, grow (displacing on pool exhaustion),
         then one fixed-shape batched model call — monolithic policy: a
-        per-admission prefill plus a ``[slots, 1]`` decode; chunked policy:
-        a single fused ragged ``[slots, chunk_tokens]`` step in which every
-        active row carries 1 (decoding) to ``chunk_tokens`` (prefilling)
-        new positions.  Returns requests finished during this step —
-        including requests shed at admission, cancelled via
-        :meth:`cancel`, and (when ``now`` carries a clock) requests whose
-        ``deadline_s``/``max_queue_s`` elapsed, with finish reasons
-        ``rejected``/``cancelled``/``timeout``/``error``.
+        per-admission prefill plus a ``[slots, 1]`` decode; chunked policy
+        (``chunk_tokens`` set): the flat ``[1, W]`` step
+        (:meth:`_step_flat`), in which every active row is a segment of 1
+        (decoding) to ``chunk_tokens`` (prefilling) tokens.  Returns
+        requests finished during this step — including requests shed at
+        admission, cancelled via :meth:`cancel`, and (when ``now`` carries
+        a clock) requests whose ``deadline_s``/``max_queue_s`` elapsed,
+        with finish reasons ``rejected``/``cancelled``/``timeout``/
+        ``error``.
 
         The step is one ``engine.step`` span of the telemetry's
         (:meth:`repro.obs.telemetry.NullTelemetry.span`), its phases
@@ -559,13 +543,10 @@ class Engine:
         t0 = time.perf_counter()
         with self.obs.span("engine.step"):
             self.obs.step_begin()
-            if self.flat:
+            if self.chunked:
                 return self._step_flat(t0, now, greedy, seed)
             finished = self._take_finished(now)
-            if self.chunked:
-                finished.extend(self._step_chunked(now, greedy, seed))
-            else:
-                finished.extend(self._step_monolithic(now, greedy, seed))
+            finished.extend(self._step_monolithic(now, greedy, seed))
             self._end_step(t0, now, finished)
             return finished
 
@@ -686,114 +667,6 @@ class Engine:
             for slot, req in list(running.items()):
                 self._verify_decode_row(req, drafts.get(slot, []), rows[slot],
                                         neff[slot], greedy, seed, finished)
-        return finished
-
-    def _step_chunked(self, now, greedy: bool, seed: int) -> List[Request]:
-        """The fused ragged step.  Decoding rows carry their fed-back token
-        at position ``len`` (``new_counts == 1``); prefilling rows carry the
-        next ``plan[slot]``-token slice of their prompt at positions
-        ``prefill_cursor ..`` (``new_counts == n``); displaced/stalled/free
-        rows are inert (``new_counts == 0``, zero block table — masked into
-        the trash page).  Causal masking *within* a chunk against the paged
-        past comes from the per-row 2-D positions the paged attention path
-        already implements, so a chunk's logits at its last valid token
-        equal the monolithic prefill's — chunking is invisible in the
-        tokens (asserted by tests and the benchmark A/B)."""
-        sched = self.scheduler
-        finished = []
-        sched.admit(now)
-        # decode growth first: decodes are never stalled behind prefill work
-        # (Sarathi's decode-prioritized schedule); a mid-prefill victim is
-        # paused with its pages, not recomputed.  Speculation rides the
-        # same fused step: a decode row's new_counts becomes 1 + its draft
-        # length, pulled from the same shape ladder as prefill chunks
-        drafts = self._draft_and_grow()
-        running = sched.running
-        if not running:
-            return finished
-        neff = self._grant_drafts(running, drafts)
-        ndecode = sum(neff.values())
-        plan = sched.plan_chunks(self.token_budget - ndecode)
-        use_chunk = any(n > 0 for n in plan.values())
-        b, mp = self.slots, self.max_pages
-        widest = max(max(plan.values(), default=0),
-                     max(neff.values(), default=0))
-        s = self._chunk_shape(widest) if (use_chunk or widest > 1) else 1
-        spec = any(n > 1 for n in neff.values())
-        k1 = self.spec_tokens + 1 if spec else 1
-        token = np.zeros((b, s), np.int32)
-        lens = np.zeros((b,), np.int32)
-        counts = np.zeros((b,), np.int32)
-        bt = np.zeros((b, mp), np.int32)
-        idx = np.zeros((b, k1), np.int32) if spec else None
-        for slot, req in running.items():
-            if req.status == "running":
-                self._fill_decode_row(slot, req, neff[slot], drafts,
-                                      token, lens, counts, bt, idx)
-            else:
-                n = plan.get(slot, 0)
-                if n == 0:
-                    continue              # stalled this step: inert row
-                cur = req.prefill_cursor
-                token[slot, :n] = req.prompt[cur:cur + n]
-                lens[slot] = cur
-                counts[slot] = n
-                bt[slot] = req.pages.block_row(mp)
-                if spec:
-                    idx[slot] = n - 1     # its last chunk token, read at j=0
-        total_new = int(counts.sum())
-        if total_new == 0:
-            self._watchdog_trips += 1
-            raise StallError(
-                "fused step scheduled zero tokens with live slots: " +
-                ", ".join(f"rid {r.rid} ({r.status}, cursor "
-                          f"{r.prefill_cursor}/{r.prompt_len}, len {r.len})"
-                          for r in running.values()))
-        # decodes (and their drafts) are unconditional; only prefill tokens
-        # are budget-capped
-        assert total_new <= max(self.token_budget, ndecode)
-        self._active_rows += int((counts > 0).sum())
-        self._mixed_steps += int(use_chunk)
-        rows = self._run_paged(token, bt, lens, counts, idx,
-                               prefill_tokens=total_new - ndecode,
-                               decode_tokens=ndecode)
-        self.obs.step_family(f"chunk[{b},{s}]" + ("/verify" if spec else ""),
-                             total_new, b * s)
-        for slot, req in list(running.items()):
-            if req.status == "running":
-                self._verify_decode_row(req, drafts.get(slot, []), rows[slot],
-                                        neff[slot], greedy, seed, finished)
-            else:
-                n = plan.get(slot, 0)
-                if n == 0:
-                    continue
-                if self.nan_guard and not np.isfinite(rows[slot]).all():
-                    # before the cursor advance and the cache insert:
-                    # a poisoned chunk's pages must never be shared
-                    self._quarantine(req, finished)
-                    continue
-                req.prefill_cursor += n
-                req.len = req.prefill_cursor
-                req.chunk_steps += 1
-                self._prefill_tokens += n
-                self.obs.request_prefill_chunk(req, n)
-                if self.prefix_cache is not None:
-                    # write newly-completed full pages into the cache as
-                    # the cursor advances — a later arrival (or this
-                    # request's own preempt-resume) shares them mid-stream
-                    self.prefix_cache.insert(req.prompt, req.pages.pages,
-                                             req.prefill_cursor)
-                if req.prefill_cursor < req.prompt_len:
-                    continue              # more chunks to come
-                # prefill complete: the logits at the last prompt token are
-                # the first-token distribution, exactly as in monolithic
-                req.status = "running"
-                self.obs.request_prefill_done(req)
-                req.out_tokens.append(
-                    self._pick(rows[slot, 0], req, greedy, seed))
-                if req.done():
-                    sched.finish(req)
-                    finished.append(req)
         return finished
 
     def _step_flat(self, t0: float, now, greedy: bool,
@@ -1042,10 +915,10 @@ class Engine:
 
     def _fill_decode_row(self, slot: int, req: Request, n: int, drafts: dict,
                          token, lens, counts, bt, idx) -> None:
-        """One decode row of the fused batch: the fed-back token plus the
-        row's granted drafts at positions ``req.len ..``; ``idx`` (when the
-        step carries any drafted row) reads logits at each fed position,
-        clamped to the row's own width."""
+        """One decode row of the monolithic batch: the fed-back token plus
+        the row's granted drafts at positions ``req.len ..``; ``idx`` (when
+        the step carries any drafted row) reads logits at each fed
+        position, clamped to the row's own width."""
         token[slot, 0] = req.out_tokens[-1]
         if n > 1:
             token[slot, 1:n] = drafts[slot]
@@ -1083,7 +956,7 @@ class Engine:
 
     def _run_paged(self, token, bt, lens, counts, idx, *,
                    prefill_tokens: int, decode_tokens: int) -> np.ndarray:
-        """One fused paged step; returns per-row logits [B, K, V] (K = 1
+        """One paged decode step; returns per-row logits [B, K, V] (K = 1
         without speculation).  The token counts label the
         ``engine.dispatch`` span."""
         logits, self.caches = self._call(
@@ -1168,27 +1041,6 @@ class Engine:
             b *= 2
         return min(b, round_up(self.scheduler.max_len, self._bucket))
 
-    def _chunk_shapes(self) -> List[int]:
-        """The fused step's geometric shape ladder: ``chunk_tokens`` halved
-        down to the layout tile (``m_r``), descending.  A step only pays
-        for the largest chunk it actually carries — a final remainder chunk
-        or a short-prompt admission rides a half/quarter-size shape — while
-        the compile count stays ``log2(chunk/m_r)+2`` with the ``[slots,1]``
-        decode shape, still below the monolithic policy's prompt buckets."""
-        shapes = [self.chunk_tokens]
-        while (shapes[-1] % 2 == 0 and shapes[-1] // 2 >= self._bucket
-               and (shapes[-1] // 2) % self._bucket == 0):
-            shapes.append(shapes[-1] // 2)
-        return shapes
-
-    def _chunk_shape(self, n: int) -> int:
-        """Smallest ladder shape holding an ``n``-token chunk."""
-        s = self.chunk_tokens
-        for cand in self._chunk_shapes():
-            if cand >= n:
-                s = cand
-        return s
-
     def warmup(self) -> None:
         """Pre-compile every step shape this engine can hit before taking
         traffic (:meth:`_warmup_shapes`), then — when telemetry is live —
@@ -1210,14 +1062,13 @@ class Engine:
 
     def _warmup_shapes(self) -> None:
         """Pre-compile every step shape this engine can hit before taking
-        traffic — chunked: the fused ``[slots, c]`` step for every ladder
-        shape ``c`` (``chunk_tokens`` halved down to ``m_r``) plus the
-        ``[slots, 1]`` decode step; monolithic: the
+        traffic — chunked: the flat ``[1, w]`` step for every width ``w``
+        of the ladder (:meth:`_flat_shapes`); monolithic: the ``[slots, 1]``
         decode step plus each geometric prefill bucket.  With speculation
-        on, additionally the verify variants: each decode-capable shape
-        with the ``[slots, spec_tokens+1]`` logits gather (drafted steps
-        read k+1 positions per row), and the drafter's own step shapes
-        (``Drafter.warmup``).  After warmup a
+        on, additionally the verify variants: each flat width with the
+        ``slots * (spec_tokens+1)`` logits gather, or the monolithic
+        ``[slots, spec_tokens+1]`` verify shape, and the drafter's own step
+        shapes (``Drafter.warmup``).  After warmup a
         trace with admissions, chunked prefills, growth, preemption and
         speculation triggers zero new XLA compilations (regression-tested
         via the model's trace counter).  Safe on an idle engine: the warmup
@@ -1231,7 +1082,7 @@ class Engine:
             self._copy_page(0, 0)
         zb = jnp.zeros((self.slots,), jnp.int32)
         btb = jnp.zeros((self.slots, self.max_pages), jnp.int32)
-        if self.flat:
+        if self.chunked:
             # every ladder width × every logits-gather width (spec steps
             # read slots*(k+1) flat positions, draft-free steps slots*1);
             # all-padding streams (row_ids == -1) route writes to the trash
@@ -1247,24 +1098,6 @@ class Engine:
                         jnp.zeros((1, w), jnp.int32), btb, pad, qz,
                         jnp.zeros((self.slots * k1,), jnp.int32))
             if self.spec_tokens is not None:
-                self.drafter.warmup()
-            return
-        idxz = (None if self.spec_tokens is None else
-                jnp.zeros((self.slots, self.spec_tokens + 1), jnp.int32))
-        if self.chunked:
-            for s in self._chunk_shapes() + [1]:
-                _, self.caches = self._paged_step(
-                    self.params, self.caches,
-                    jnp.zeros((self.slots, s), jnp.int32), btb, zb, zb, None)
-            if idxz is not None:
-                # any ladder shape can carry drafted rows (verify width
-                # rides the chunk ladder; [slots, 1] never does — a drafted
-                # step is at least spec_tokens+1 wide)
-                for s in self._chunk_shapes():
-                    _, self.caches = self._paged_step(
-                        self.params, self.caches,
-                        jnp.zeros((self.slots, s), jnp.int32), btb, zb, zb,
-                        idxz)
                 self.drafter.warmup()
             return
         zero = jnp.zeros((1,), jnp.int32)
@@ -1286,11 +1119,10 @@ class Engine:
         _, self.caches = self._paged_step(
             self.params, self.caches, jnp.zeros((self.slots, 1), jnp.int32),
             btb, zb, zb, None)
-        if idxz is not None:       # the monolithic verify shape
+        if self.spec_tokens is not None:       # the monolithic verify shape
+            k1z = jnp.zeros((self.slots, self.spec_tokens + 1), jnp.int32)
             _, self.caches = self._paged_step(
-                self.params, self.caches,
-                jnp.zeros((self.slots, self.spec_tokens + 1), jnp.int32),
-                btb, zb, zb, idxz)
+                self.params, self.caches, k1z, btb, zb, zb, k1z)
             self.drafter.warmup()
 
     def _prefill_request(self, req: Request, greedy: bool, seed: int) -> bool:

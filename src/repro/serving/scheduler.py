@@ -87,29 +87,26 @@ list runs dry, and availability checks count ``pool.num_available``), so
 every preemption/termination argument above survives the cache holding
 pages.
 
-A note on the token budget: under the dense chunked policy the engine's
-step *shape* is fixed at ``(slots, chunk_tokens)`` whenever any slot
-prefills (the paper's fixed-shape-grid philosophy: one compiled shape,
-occupancy varies via ``new_counts``), so per-step device compute is
-bounded by the shape, not the budget.  ``chunk_tokens`` is therefore the
-latency knob; the ``token_budget`` cap on total assigned new tokens
-additionally bounds how many slots prefill concurrently (page-allocation
-raggedness), and decoding slots are never budget-stalled — decode
-progress is unconditional.
+A note on the token budget: ``chunk_tokens`` caps one row's prefill
+segment per step, and the ``token_budget`` cap on total assigned new
+tokens bounds the whole step — so it bounds both how many slots prefill
+concurrently (page-allocation raggedness) and the widest compiled step;
+decoding slots are never budget-stalled — decode progress is
+unconditional.
 
-**Flat-segment layout contract** (the default engine step since the flat
-refactor; :meth:`Scheduler.plan_segments`): the step is one ``[1, W]``
-token stream, ``W`` the token budget rounded up to the layout's ``m_r``
-(tile writes stay whole).  Each scheduled row occupies a contiguous
-*segment* of the stream: position ``i`` carries ``row_ids[i]`` (the
-slot; ``-1`` = padding) and ``q_pos[i]`` (the token's absolute position
-in that row — its segment offset plus the row's cursor/len), and the
-attention mask is segment-aware causal (``kv_pos <= q_pos[i]`` within
-the row's own page stream, see kernels/ragged_attn).  A decode row costs
+**Flat-segment layout contract** (the engine's one step with
+``chunk_tokens`` set; :meth:`Scheduler.plan_segments`): the step is one
+``[1, W]`` token stream, ``W`` from a geometric ladder of the layout's
+``m_r`` multiples capped at the token budget (tile writes stay whole).
+Each scheduled row occupies a contiguous *segment* of the stream:
+position ``i`` carries ``row_ids[i]`` (the slot; ``-1`` = padding) and
+``q_pos[i]`` (the token's absolute position in that row — its segment
+offset plus the row's cursor/len), and the attention mask is
+segment-aware causal (``kv_pos <= q_pos[i]`` within the row's own page
+stream, see kernels/ragged_attn).  A decode row costs
 exactly its ``1 + granted_drafts`` real positions — not a padded
 chunk-width row — so the budget is token-exact: ``sum(segment lengths)
-<= token_budget`` counts only real tokens, the per-token padding tax of
-the dense ``[slots, chunk]`` grid is gone, and decode segments are still
+<= token_budget`` counts only real tokens, and decode segments are
 never budget-stalled (they are planned before prefill chunks).
 
 ``eager=True`` restores the PR-1 policy (reserve the full lifetime at
@@ -664,13 +661,11 @@ class Scheduler:
         """Flat-segment plan for one ``[1, W]`` step: decode rows first
         (each costs exactly its ``1 + granted_drafts`` real positions —
         token-exact, never budget-stalled), then prefill chunks from
-        :meth:`plan_chunks` under the remaining budget (same page
-        bookkeeping, stalls, and reclaim fallbacks as the dense path — the
-        flat layout changes how tokens are *shaped*, not how they are
-        scheduled).  ``decode_counts``: ``{slot: 1 + k}`` for every
-        decoding row.  Returns an ordered ``[(slot, kind, n)]`` list,
-        ``kind in {"decode", "prefill"}``; the engine lays the segments
-        out back-to-back in the flat stream."""
+        :meth:`plan_chunks` under the remaining budget (page bookkeeping,
+        stalls, and reclaim fallbacks live there).  ``decode_counts``:
+        ``{slot: 1 + k}`` for every decoding row.  Returns an ordered
+        ``[(slot, kind, n)]`` list, ``kind in {"decode", "prefill"}``; the
+        engine lays the segments out back-to-back in the flat stream."""
         ndecode = sum(decode_counts.values())
         plan = self.plan_chunks(budget - ndecode)
         segs: List[tuple] = []

@@ -1,4 +1,4 @@
-"""Speculative decoding over the fused ragged step: drafters + acceptance.
+"""Speculative decoding over the batched serving step: drafters + acceptance.
 
 The fused ``[slots, s]`` ragged step (PR 3) lets one row carry several new
 positions per engine tick — which is exactly the **verify** primitive
@@ -32,9 +32,9 @@ target's categorical draw is a point mass, ``min(1, p/q)`` acceptance
 degenerates to equality with that draw, and any other rule would break
 token identity.  Greedy is the ``argmax`` special case.  Outputs are
 asserted bit-identical to the non-speculative engine in
-``tests/test_speculative.py`` and ``benchmarks/bench_serving.py`` — the
-drafter only ever changes *throughput*, never tokens, so drafters are free
-to be wrong, stale, or heuristic.
+``tests/test_speculative.py`` — the drafter only ever changes
+*throughput*, never tokens, so drafters are free to be wrong, stale, or
+heuristic.
 
 Two drafters ship:
 

@@ -69,8 +69,7 @@ def test_sanitized_traffic_green(smollm):
     watching stays clean — and the sanitizer demonstrably inspected the
     steps it certified."""
     model, params = smollm
-    engine = Engine(model, params, chunk_tokens=16, prefix_cache=True,
-                    flat=False)
+    engine = Engine(model, params, chunk_tokens=16, prefix_cache=True)
     san = install(engine)
     det = RetraceDetector(model)
     engine.warmup()
@@ -93,9 +92,9 @@ def test_ast_lint_green_on_tree():
 
 def test_seeded_misaligned_chunk(smollm):
     """chunk_tokens hacked to a non-m_r multiple after construction: the
-    linter re-derives the ladder and names the width and m_r."""
+    linter names the width and m_r."""
     model, params = smollm
-    engine = Engine(model, params, chunk_tokens=16, flat=False)
+    engine = Engine(model, params, chunk_tokens=16)
     engine.chunk_tokens = 11          # m_r = 8: not tile-aligned
     findings = lint_engine_shapes(engine, "seeded", trace=False)
     rules = {f.rule for f in findings}
@@ -165,7 +164,6 @@ def test_sanitizer_write_to_freed_page():
             self.pool = pool
             self._bucket = 8
             self.chunked = False
-            self.flat = False
             self.spec_tokens = None
 
         def _prefill_bucket(self, l):

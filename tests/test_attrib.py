@@ -56,9 +56,9 @@ def _drain(eng, reqs, **kw):
 
 REQS = ([13, 21, 3, 16], [8, 6, 10, 7])
 
-# engine grids under test: dense chunked, flat token-level, and flat
-# with an n-gram drafter (speculation exercises the draft span)
-GRIDS = [dict(chunk_tokens=16, flat=False),
+# engine grids under test: flat with a prefix cache, flat token-level,
+# and flat with an n-gram drafter (speculation exercises the draft span)
+GRIDS = [dict(chunk_tokens=16, token_budget=24, prefix_cache=True),
          dict(chunk_tokens=16, token_budget=24),
          dict(chunk_tokens=16, token_budget=24, spec_tokens=2)]
 
@@ -68,7 +68,7 @@ GRIDS = [dict(chunk_tokens=16, flat=False),
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("kw", GRIDS,
-                         ids=["chunked", "flat", "flat-spec"])
+                         ids=["flat-cache", "flat", "flat-spec"])
 def test_attribution_components_sum_to_wall(smollm, kw):
     """The headline property: every per-step attribution record's four
     components reconstruct the measured wall.  The split is exact by
@@ -282,7 +282,7 @@ def test_step_outlier_detects_spike_per_family():
     assert "flat[1,16]/k1" in fired[0].message
     # a different family with no warm window never alerts
     fired = mon.observe_step(t=12.0, scheduler=sched, telemetry=tel,
-                             families=[("chunk[3,16]", 8, 48, 0.500)],
+                             families=[("prefill[1,48]", 8, 48, 0.500)],
                              call_s=0.500)
     assert fired == []
 

@@ -1,4 +1,4 @@
-"""Chunked prefill under a per-step token budget (the fused ragged step).
+"""Chunked prefill under a per-step token budget (the flat step).
 
 Contracts covered:
   - chunked prefill is token-identical to monolithic prefill for chunk
@@ -357,10 +357,10 @@ def test_hybrid_families_refuse_chunking(smollm):
 # ---------------------------------------------------------------------------
 
 def test_no_compiles_after_warmup_chunked(smollm):
-    """The fused engine's step shapes are the geometric ladder
-    ([slots, chunk] .. [slots, m_r], plus [slots, 1]); after warmup, a
-    trace with admissions, chunked prefills, stalls, growth and preemption
-    must trigger zero new XLA traces."""
+    """The chunked engine's step shapes are the flat width ladder
+    (Engine._flat_shapes); after warmup, a trace with admissions, chunked
+    prefills, stalls, growth and preemption must trigger zero new XLA
+    traces."""
     cfg, m, params = smollm
     eng = Engine(m, params, max_slots=3, page_tokens=8, num_pages=1 + 6,
                  chunk_tokens=8)

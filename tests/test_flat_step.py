@@ -1,10 +1,11 @@
 """Flat token-level serving step (the [1, budget] packed layout).
 
 Contracts covered:
-  - the flat step is token-identical to both the dense chunked step and
-    the monolithic baseline — greedy and seeded-sampled — and stays so
-    under speculation (n-gram and draft-model), a prefix cache, and a
-    pool tight enough to force preemptions and mid-prefill pauses;
+  - the flat step is token-identical to the monolithic baseline — greedy
+    and seeded-sampled — and stays so under speculation (n-gram and
+    draft-model), a prefix cache, and a pool tight enough to force
+    preemptions and mid-prefill pauses;
+  - the flat step is the only batched step a chunked engine compiles;
   - after Engine.warmup() a flat drain with speculation and prefix-cache
     hits triggers zero new XLA traces on the target AND the draft model;
   - budget exactness: no flat step ever carries more real tokens than
@@ -92,28 +93,22 @@ def baseline(smollm):
 
 
 # ---------------------------------------------------------------------------
-# token identity: flat == dense chunked == monolithic
+# token identity: flat == monolithic
 # ---------------------------------------------------------------------------
 
-def test_flat_matches_chunked_and_monolithic(smollm, baseline):
-    """The tentpole identity: same prompts, three engines (flat, dense
-    chunked, monolithic), one token stream.  The budget (24) is a
-    non-divisor of most prompts so segments split mid-chunk."""
+def test_flat_matches_monolithic(smollm, baseline):
+    """The tentpole identity: same prompts, two engines (flat,
+    monolithic), one token stream.  The budget (24) is a non-divisor of
+    most prompts so segments split mid-chunk."""
     cfg, m, params = smollm
     reqs, greedy, sampled = baseline
     flat = Engine(m, params, max_slots=3, page_tokens=8, chunk_tokens=16,
                   token_budget=24)
-    assert flat.flat            # flat defaults on whenever chunking is on
     got = _drain(flat, reqs)
     assert [r.out_tokens for r in got] == greedy
     assert flat.pool.num_used == 0
     st = flat.stats()["flat"]
     assert st["steps"] > 0 and st["token_budget"] == 24
-
-    dense = Engine(m, params, max_slots=3, page_tokens=8, chunk_tokens=16,
-                   token_budget=24, flat=False)
-    assert not dense.flat
-    assert [r.out_tokens for r in _drain(dense, reqs)] == greedy
 
 
 def test_flat_matches_baseline_sampled(smollm, baseline):
@@ -126,10 +121,18 @@ def test_flat_matches_baseline_sampled(smollm, baseline):
             _drain(eng, reqs, greedy=False, seed=7)] == sampled
 
 
-def test_flat_requires_chunking(smollm):
-    cfg, m, params = smollm
-    with pytest.raises(AssertionError):
-        Engine(m, params, max_slots=3, flat=True)
+def test_chunked_engine_compiles_only_the_flat_step(smollm):
+    """With chunk_tokens set, warmup and a drain run the flat step and
+    nothing else: a fresh model (its own jit cache, so every program the
+    engine reaches is traced here) never traces the paged step."""
+    cfg, _, params = smollm
+    m = build_model(cfg, RUN, ShapeSpec("serve", 64, 3, "decode"))
+    eng = Engine(m, params, max_slots=3, chunk_tokens=16)
+    eng.warmup()
+    _drain(eng, list(zip(_prompts(cfg, REQS[0]), REQS[1])))
+    assert m.trace_counts["paged"] == 0
+    assert m.trace_counts["flat"] > 0
+    assert eng.stats()["flat"]["steps"] > 0
 
 
 def test_flat_preemption_token_identical(smollm):
@@ -161,7 +164,6 @@ def test_flat_spec_ngram_matches_baseline(smollm, baseline):
     for gr, seed, want in [(True, 0, greedy), (False, 7, sampled)]:
         eng = Engine(m, params, max_slots=3, page_tokens=8, chunk_tokens=16,
                      token_budget=24, spec_tokens=2, drafter=NgramDrafter())
-        assert eng.flat
         got = _drain(eng, reqs, greedy=gr, seed=seed)
         assert [r.out_tokens for r in got] == want
         assert eng.pool.num_used == 0

@@ -167,11 +167,12 @@ def test_trace_recorder_schema_and_bounds(tmp_path):
 # the observer effect: telemetry on == telemetry off, zero retraces
 # ---------------------------------------------------------------------------
 
-def test_telemetry_token_identity_chunked_and_flat(smollm):
+def test_telemetry_token_identity_flat_and_spec(smollm):
     cfg, m, params = smollm
     reqs = list(zip(_prompts(cfg, REQS[0]), REQS[1]))
-    for kw in [dict(chunk_tokens=16, flat=False),              # dense chunked
-               dict(chunk_tokens=16, token_budget=24)]:        # flat [1, W]
+    for kw in [dict(chunk_tokens=16, token_budget=24),         # flat [1, W]
+               dict(chunk_tokens=16, token_budget=24,          # flat + spec
+                    spec_tokens=2)]:
         for greedy, seed in [(True, 0), (False, 7)]:
             plain = Engine(m, params, max_slots=3, page_tokens=8, **kw)
             want = [r.out_tokens

@@ -4,7 +4,7 @@ Covers the resilience layer end to end:
   - identity under chaos: for seeded fault plans (OutOfPages spikes,
     drafter failures mid-spec, NaN-logit injection, page-copier
     failures), every surviving request's tokens are bit-identical to the
-    fault-free run — chunked and flat steps, greedy and sampled picks,
+    fault-free run — the flat step, greedy and sampled picks,
     prefix cache on and off — and the allocator is balanced afterwards;
   - cancellation from every lifecycle state releases every page
     (property test interleaving admit/chunk/spec/preempt/cancel over the
@@ -81,8 +81,7 @@ _PLANS = {
 }
 
 _CONFIGS = {
-    "chunked-greedy-cache": (dict(chunk_tokens=8, flat=False,
-                                  prefix_cache=True), True),
+    "flat-greedy-cache": (dict(chunk_tokens=8, prefix_cache=True), True),
     "flat-sampled": (dict(chunk_tokens=8), False),
     "flat-spec-greedy-cache": (dict(chunk_tokens=8, spec_tokens=3,
                                     prefix_cache=True), True),
@@ -171,9 +170,16 @@ def test_drafter_auto_disable_counts_and_resets(smollm):
         "auto-disable must stop calling the broken drafter"
     assert not res["spec_disabled"], "the disable is per-drain"
     assert all(reason == "length" for _, reason in out.values())
-    # a fresh drain actually speculates again
-    clean = _drain_outputs(eng, _prompts(cfg, [7]), [6])
-    assert eng._drafted > 0
+    # a fresh drain speculates again, exactly as much as a fault-free
+    # engine given the same request (16 tokens: the smoke model's output
+    # must run long enough to repeat before the n-gram drafter matches)
+    prompt = _prompts(cfg, [7])
+    before = eng._drafted
+    _drain_outputs(eng, prompt, [16])
+    control = Engine(m, params, max_slots=2, chunk_tokens=8, spec_tokens=3)
+    _drain_outputs(control, prompt, [16])
+    assert control._drafted > 0
+    assert eng._drafted - before == control._drafted
 
 
 # ---------------------------------------------------------------------------

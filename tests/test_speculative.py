@@ -1,4 +1,4 @@
-"""Speculative decoding over the fused ragged step.
+"""Speculative decoding over the batched serving step.
 
 Contracts covered:
   - spec-on outputs are token-identical to the non-speculative baseline —
@@ -149,8 +149,8 @@ def test_spec_draft_model_matches_baseline(smollm, baseline):
 
 
 def test_spec_chunked_matches_baseline(smollm, baseline):
-    """Speculation through the fused chunked step: verify widths ride the
-    same shape ladder as prefill chunks."""
+    """Speculation through the flat step: verify segments ride the same
+    width ladder as prefill chunks."""
     cfg, m, params = smollm
     reqs, greedy, sampled = baseline
     eng = Engine(m, params, max_slots=3, chunk_tokens=8, spec_tokens=2)
